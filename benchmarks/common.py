@@ -56,6 +56,16 @@ def run_metadata() -> dict:
     return meta
 
 
+def holds_accelerator() -> bool:
+    """True when this process's JAX runs on an accelerator.  The process
+    then holds its chips and a child process cannot open them, so
+    multi-device legs run here over ``jax.devices()``; only virtual CPU
+    devices (which need ``XLA_FLAGS`` before JAX starts) take a worker
+    subprocess."""
+    import jax
+    return jax.default_backend() != "cpu"
+
+
 def save_artifact(name: str, payload) -> str:
     os.makedirs(ARTIFACT_DIR, exist_ok=True)
     if isinstance(payload, dict) and "_meta" not in payload:

@@ -15,10 +15,11 @@ hardware estimate (mean bit sparsity, modeled cycles/MAC per method,
 array utilization, Table III energy).
 
 The same mix also runs as a **mesh (tensor-parallel) leg**: the identical
-workload on a ``("data", "model")`` ``MeshExecutor``, measured in a worker
-subprocess with virtual CPU devices (``XLA_FLAGS`` must be set before jax
-initializes — the ``benchmarks/sharded_serving.py`` harness pattern) and
-saved as its own gated artifact (``BENCH_production_mix_mesh.json``) with
+workload on a ``("data", "model")`` ``MeshExecutor``.  On real accelerators
+it runs in this process over ``jax.devices()``; on the CPU it runs in a
+worker subprocess with virtual devices (``XLA_FLAGS`` must be set before
+jax initializes — the ``benchmarks/sharded_serving.py`` harness pattern).
+It is saved as its own gated artifact (``BENCH_production_mix_mesh.json``) with
 its own telemetry files (``production_mix_mesh_*``).
 
     PYTHONPATH=src python benchmarks/production_mix.py [--tiny]
@@ -242,12 +243,20 @@ def run_mesh_leg(mesh_shape, *, tiny: bool = False, seed: int = 0,
                  n_requests: int = None, rate: float = 0.7,
                  block_size: int = 8, telemetry_dir: str = None,
                  matmul_backend: str = None) -> dict:
-    """Run the mix on a ``("data", "model")`` mesh in a worker subprocess.
+    """Run the mix on a ``("data", "model")`` mesh.
 
-    Virtual CPU devices need ``XLA_FLAGS`` set before jax initializes and
-    the parent's jax is already initialized single-device, so the mesh leg
-    reuses the ``benchmarks/sharded_serving.py`` worker harness: spawn this
-    script with ``--worker``, parse its last-line JSON."""
+    On real accelerators this process already holds every chip, so the leg
+    runs here over ``jax.devices()``.  Virtual CPU devices need
+    ``XLA_FLAGS`` set before jax initializes and the parent's jax is
+    already initialized single-device, so there the mesh leg reuses the
+    ``benchmarks/sharded_serving.py`` worker harness: spawn this script
+    with ``--worker``, parse its last-line JSON."""
+    from benchmarks.common import holds_accelerator
+    if holds_accelerator():
+        return run(tiny=tiny, seed=seed, probe_every=probe_every,
+                   n_slots=n_slots, n_requests=n_requests, rate=rate,
+                   block_size=block_size, telemetry_dir=telemetry_dir,
+                   mesh_shape=mesh_shape, matmul_backend=matmul_backend)
     n_dev = int(mesh_shape[0]) * int(mesh_shape[1])
     env = dict(os.environ)
     flags = [f for f in env.get("XLA_FLAGS", "").split()

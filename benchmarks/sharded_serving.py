@@ -11,11 +11,12 @@ shard_map-partitioned) — and reports per-step decode latency percentiles
 (greedy outputs MUST match across every cell; the acceptance bar is 0
 mismatches).
 
-Virtual CPU devices need ``XLA_FLAGS`` set before jax initializes, so the
-measurement runs in a WORKER SUBPROCESS (``--worker``); the parent (the CLI
-or ``benchmarks/run.py``, whose process has already initialized jax
-single-device) parses the worker's JSON.  On real TPU slices the worker
-runs against the physical devices unchanged.
+On real accelerators the measurement runs in this process over
+``jax.devices()`` (a process that has touched JAX holds the chips, so a
+child could not open them).  Virtual CPU devices need ``XLA_FLAGS`` set
+before jax initializes, so there the measurement runs in a WORKER
+SUBPROCESS (``--worker``) and the parent (the CLI or ``benchmarks/run.py``)
+parses the worker's JSON.
 
 On virtual CPU devices the mesh numbers measure dispatch + emulated
 collective overhead (and the kernel cells pay the Pallas interpreter), not
@@ -150,11 +151,15 @@ def _measure(tiny: bool, mesh_shape, seed: int, backend: str,
 def run(tiny: bool = False, mesh_shape=(2, 4), seed: int = 0,
         backend: str = "slab", n_requests: int = None,
         rate: float = 0.5) -> dict:
-    """Spawn the worker with enough virtual devices and parse its JSON.
-    (Callable from ``benchmarks/run.py``, whose jax is already initialized
-    single-device — device count is locked at first backend init.)"""
+    """Measure in-process on real accelerators; on the CPU spawn the
+    worker with enough virtual devices and parse its JSON.  (Callable from
+    ``benchmarks/run.py``, whose jax is already initialized — device count
+    is locked at first backend init.)"""
+    from benchmarks.common import holds_accelerator
     if n_requests is None:
         n_requests = 6 if tiny else 16
+    if holds_accelerator():
+        return _measure(tiny, mesh_shape, seed, backend, n_requests, rate)
     n_dev = int(mesh_shape[0]) * int(mesh_shape[1])
     env = dict(os.environ)
     flags = [f for f in env.get("XLA_FLAGS", "").split()

@@ -30,6 +30,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serving import (FrontDoor, FrontDoorClient, Replica,
                            SchedulerConfig, ServeConfig, ServingEngine,
@@ -110,6 +111,7 @@ def main() -> None:
                    help="start and block until Ctrl-C instead of driving "
                         "demo traffic")
     args = p.parse_args()
+    enable_compile_cache()
 
     fd = build_door(args).start()
     try:

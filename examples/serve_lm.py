@@ -53,6 +53,7 @@ import numpy as np
 import jax
 
 from repro.configs.base import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.models.layers import quantize_dense_params
 from repro.serving import (Request, SchedulerConfig, ServeConfig,
@@ -107,6 +108,7 @@ def main():
                          "the paper's cost models — needs a bp_* --mode; "
                          "emits hw_estimate records when --metrics is set")
     args = ap.parse_args()
+    enable_compile_cache()
     mesh_shape = _MESH     # parsed+validated pre-import (sets XLA_FLAGS)
     if args.draft != "none" and args.temperature > 0:
         print(f"--draft {args.draft}: speculative decoding is greedy-only, "

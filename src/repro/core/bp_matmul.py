@@ -204,7 +204,9 @@ def _qmm_fwd_impl(x, w, w_scale, mode):
                             interpret=interpret)
         return out.astype(x.dtype)
     acc = bp_matmul_int(x_q, w, mode)
-    return (acc.astype(jnp.float32) * (x_scale * w_scale)).astype(x.dtype)
+    # the kernel's epilogue order, (acc * x_scale) * w_scale, so both
+    # backends round the dequantized product alike
+    return (acc.astype(jnp.float32) * x_scale * w_scale).astype(x.dtype)
 
 
 def _qmm_fwd(x, w, w_scale, mode):

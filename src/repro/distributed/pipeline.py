@@ -30,14 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """Version-portable shard_map (see
-    :func:`repro.distributed.sharding.portable_shard_map`, the shared
-    implementation also used by the kernel wrappers)."""
-    from repro.distributed.sharding import portable_shard_map
-    return portable_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)
+from repro.distributed.sharding import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, x, *, mesh,
@@ -97,7 +90,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *, mesh,
                       jnp.zeros_like(outputs)), axis_name)
         return outputs
 
-    fn = _shard_map(
+    fn = shard_map(
         per_stage, mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P())

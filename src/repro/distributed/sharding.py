@@ -85,67 +85,31 @@ def recipe(name: Optional[str]):
         _STATE.rules = prev
 
 
-def _physical_mesh():
-    """Thread-local physical mesh set by ``with Mesh(...)`` (None when the
-    legacy context API is gone)."""
-    try:
-        from jax.interpreters import pxla
-        return pxla.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError):
-        return None
-
-
 def _mesh_axes():
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        mesh = get_abstract()
-        if mesh is None or mesh.empty:
-            # `with Mesh(...)` (the jax<0.5 idiom) still sets the physical
-            # mesh on newer jax — fall through so both activation styles work
-            mesh = _physical_mesh()
-    else:  # jax < 0.5: the thread-local physical mesh set by `with Mesh(...)`
-        mesh = _physical_mesh()
-    if mesh is None or mesh.empty:
-        return None
-    return dict(zip(mesh.axis_names, mesh.shape.values())) if hasattr(mesh.shape, "values") else dict(mesh.shape)
+    mesh = current_mesh()
+    return None if mesh is None else mesh_axes_dict(mesh)
 
 
 def mesh_axes_dict(mesh) -> dict:
-    """{axis name: size} for a concrete ``jax.sharding.Mesh``."""
-    if hasattr(mesh, "devices"):
-        return dict(zip(mesh.axis_names, mesh.devices.shape))
-    return dict(mesh.shape)
+    """{axis name: size} for a ``Mesh`` or an ``AbstractMesh``."""
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def current_mesh():
-    """The mesh active for this trace — the abstract mesh on jax versions
-    that have one, else the thread-local physical mesh set by ``with
-    Mesh(...)`` / :func:`activate_mesh`.  Returns an object usable as the
-    ``mesh`` argument of ``shard_map``, or None when no mesh is active."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        mesh = get_abstract()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    mesh = _physical_mesh()
-    if mesh is None or mesh.empty:
-        return None
-    return mesh
+    """The abstract mesh active for this trace (set by ``jax.set_mesh``),
+    usable as the ``mesh`` argument of ``shard_map``; None when no mesh is
+    active."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
-def portable_shard_map(fn, *, mesh, in_specs, out_specs):
-    """Version-portable shard_map: ``jax.shard_map`` (jax >= 0.7,
-    ``check_vma``) with the ``jax.experimental`` spelling (``check_rep``)
-    as fallback.  Replication checking is off in both: the kernel wrappers
-    produce outputs whose replication the tracer cannot prove (psum-combined
-    partial contractions), and parity tests assert it instead."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+def shard_map(fn, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication checking off: the kernel wrappers
+    produce outputs whose replication the tracer cannot prove
+    (psum-combined partial contractions), and parity tests assert it
+    instead."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def combine_matmul_partials(acc, axis_name: str):
@@ -173,19 +137,6 @@ def combine_softmax_state(acc, m, l, axis_name: str, *, eps: float = 1e-37):
     l_all = jax.lax.psum(l * corr, axis_name)
     acc_all = jax.lax.psum(acc * corr, axis_name)
     return acc_all / jnp.maximum(l_all, eps)
-
-
-def activate_mesh(mesh):
-    """Context manager activating ``mesh`` for trace-time logical-axis
-    constraints across jax versions: ``jax.set_mesh`` /
-    ``jax.sharding.use_mesh`` where the abstract-mesh API exists, the
-    legacy ``with Mesh(...)`` physical-mesh context otherwise.
-    ``shard``/:func:`force_replicated` read whichever is active."""
-    for ctx in (getattr(jax, "set_mesh", None),
-                getattr(jax.sharding, "use_mesh", None)):
-        if ctx is not None:
-            return ctx(mesh)
-    return mesh  # jax < 0.5: Mesh is itself a context manager
 
 
 def force_replicated(x):

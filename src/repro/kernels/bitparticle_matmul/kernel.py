@@ -32,13 +32,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 
 def _int8_dot(a, w):
-    """int8 x int8 -> int32 MXU contraction of (bm, bk) x (bk, bn)."""
+    """int8 x int8 -> int32 MXU contraction of (bm, bk) x (bk, bn).
+
+    The precision is pinned: the TPU compiler refuses an int8 matmul at a
+    raised precision, which a process-wide ``jax_default_matmul_precision``
+    would otherwise impose (the integer result is exact either way)."""
     return jax.lax.dot_general(
-        a, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
+        a, w, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.int32
     )
 
 
@@ -121,7 +125,7 @@ def bp_matmul_kernel(a_q, w_q, scale_a, scale_w, *, approx: bool = False,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -156,7 +156,7 @@ def bp_matmul_sharded(a_q, w_q, scale_a=None, scale_w=None, *,
             return acc.astype(jnp.float32) * sa * sw
         return bp_matmul(aq, wq, sa, sw, approx=approx, interpret=interpret)
 
-    fn = shd.portable_shard_map(
+    fn = shd.shard_map(
         run, mesh=mesh, in_specs=(a_spec, w_spec, sa_spec, sw_spec),
         out_specs=out_spec)
     return fn(a_q, w_q, sa, sw)

@@ -19,10 +19,12 @@ valid (the just-written token's K/V included), matching
 page 0 (the pool's trash block); their scores are masked to -inf before the
 softmax so they contribute exactly 0.
 
-VMEM working set per step: one (block_size, D) K page + V page + the
-(G, D) accumulator — a few KiB.  For compiled TPU use, prefer
-``block_size`` a multiple of 8 and head dim a multiple of 128; interpret
-mode (the CPU validation path) relaxes all tiling.
+Pages are laid out ``(N, KV_heads, block_size, D)``: each grid step reads
+one ``(block_size, D)`` tile, so the block's minor two dims are what the
+TPU compiler tiles.  Compiled for the chip, ``block_size`` must be a
+multiple of 8 and D a multiple of 128; interpret mode
+(the CPU validation path) relaxes all tiling.  VMEM working set per step:
+one K tile + one V tile + the (G, D) accumulator, a few KiB.
 """
 
 from __future__ import annotations
@@ -33,8 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -55,8 +55,8 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *refs,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)          # (G, D), pre-scaled
-    k = k_ref[0, :, 0].astype(jnp.float32)       # (block_size, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[...].astype(jnp.float32)           # (block_size, D)
+    v = v_ref[...].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (G, bs)
@@ -96,7 +96,7 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *refs,
 def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
                            interpret: bool = False,
                            return_state: bool = False):
-    """q: (B, H, D); k_pages/v_pages: (N, block_size, KH, D);
+    """q: (B, H, D); k_pages/v_pages: (N, KH, block_size, D);
     block_tables: (B, n_pages) int32 physical page ids; lengths: (B,) int32
     last valid position (inclusive).  Returns (B, H, D) in q.dtype.
 
@@ -106,7 +106,7 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
     shard split-KV combine.  A caller whose table covers only masked
     positions gets m = -inf, l = 0, acc = 0 (a neutral element)."""
     B, H, D = q.shape
-    N, bs, KH, _ = k_pages.shape
+    N, KH, bs, _ = k_pages.shape
     G = H // KH
     n_pages = block_tables.shape[1]
     scale = D ** -0.5
@@ -115,6 +115,11 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
     kern = functools.partial(_kernel, block_size=bs, n_pages=n_pages,
                              return_state=return_state)
     out_block = pl.BlockSpec((1, 1, G, D), lambda b, h, p, bt, ln: (b, h, 0, 0))
+    # one (block_size, D) tile of one KV head of one physical page: the
+    # page and head dims are squeezed, so the block's minor two dims are
+    # (block_size, D) -- tile-aligned for the chip's (8, 128) layout
+    kv_block = pl.BlockSpec((None, None, bs, D),
+                            lambda b, h, p, bt, ln: (bt[b, p], h, 0, 0))
     state_block = pl.BlockSpec((1, 1, G, 1),
                                lambda b, h, p, bt, ln: (b, h, 0, 0))
     if return_state:
@@ -130,10 +135,8 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
         grid=(B, KH, n_pages),
         in_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, h, p, bt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, p, bt, ln: (bt[b, p], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D),
-                         lambda b, h, p, bt, ln: (bt[b, p], 0, h, 0)),
+            kv_block,
+            kv_block,
         ],
         out_specs=out_specs,
         scratch_shapes=[
@@ -146,7 +149,7 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
         kern,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="paged_attention_decode",

@@ -77,7 +77,7 @@ def _paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths, *,
     model = axes.get("model", 1)
     data = axes.get("data", 1)
     B, H, D = q.shape
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
     n_pages = block_tables.shape[1]
     batch_axis = "data" if (data > 1 and B % data == 0) else None
     kv_split = model > 1 and n_pages % model == 0
@@ -98,7 +98,7 @@ def _paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths, *,
         return paged_attention_kernel(q_l, kp, vp, bt_l, ln_l,
                                       interpret=interpret)
 
-    fn = shd.portable_shard_map(
+    fn = shd.shard_map(
         run, mesh=mesh,
         in_specs=(P(batch_axis, None, None),
                   P(None, None, None, None),

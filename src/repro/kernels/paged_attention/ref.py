@@ -21,19 +21,20 @@ NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def gather_pages(pages, block_tables):
-    """(N, bs, ...) pages + (B, P) tables -> (B, P*bs, ...) dense view."""
-    g = pages[block_tables]                       # (B, P, bs, ...)
+    """(N, KH, bs, ...) pages + (B, P) tables -> (B, P*bs, KH, ...) dense
+    view (the slab cache layout)."""
+    g = jnp.swapaxes(pages[block_tables], 2, 3)   # (B, P, bs, KH, ...)
     B, P, bs = g.shape[:3]
     return g.reshape(B, P * bs, *g.shape[3:])
 
 
 def paged_attention_xla(q, k_pages, v_pages, block_tables, lengths, *,
                         k_scale_pages=None, v_scale_pages=None):
-    """q: (B, H, D); k_pages/v_pages: (N, bs, KH, D); block_tables: (B, P);
+    """q: (B, H, D); k_pages/v_pages: (N, KH, bs, D); block_tables: (B, P);
     lengths: (B,) last valid position (inclusive).  Optional int8-KV scale
-    pages: (N, bs, KH).  Returns (B, H, D) in q.dtype."""
+    pages: (N, KH, bs).  Returns (B, H, D) in q.dtype."""
     B, H, D = q.shape
-    KH = k_pages.shape[2]
+    KH = k_pages.shape[1]
     G = H // KH
     k = gather_pages(k_pages, block_tables)       # (B, T, KH, D)
     v = gather_pages(v_pages, block_tables)

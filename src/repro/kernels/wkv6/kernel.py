@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, out_ref, sout_ref,
@@ -104,7 +103,7 @@ def wkv6_kernel(r, k, v, log_w, u, state, *, chunk: int = 64,
         out_shape=[jax.ShapeDtypeStruct((R, T, N), jnp.float32),
                    jax.ShapeDtypeStruct((R, N, N), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="wkv6_chunked",

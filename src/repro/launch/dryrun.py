@@ -232,8 +232,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool, out_dir: str,
     t0 = time.time()
     try:
         mesh = mesh_lib.make_production_mesh(multi_pod=multi_pod)
-        from repro.distributed.sharding import activate_mesh
-        with activate_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn, args, recipe = build_cell(arch_id, shape_name, mesh, variant)
             lowered = fn.lower(*args)
             t_lower = time.time()

@@ -7,20 +7,12 @@ dry-run must set XLA_FLAGS before anything else — see dryrun.py).
 
 from __future__ import annotations
 
-import numpy as np
 import jax
 
 
 def _make_mesh(shape, axes):
-    """Version-portable mesh construction: ``axis_types`` where the new
-    API exists (jax >= 0.5), a plain device-grid ``Mesh`` otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    n = int(np.prod(shape))
-    return jax.sharding.Mesh(
-        np.asarray(jax.devices()[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

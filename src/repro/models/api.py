@@ -270,14 +270,17 @@ def shard_cache(cfg: ArchConfig, cache, *, paged: bool = False):
 
 def paged_cache_specs(cfg: ArchConfig, num_blocks: int, block_size: int):
     """ShapeDtypeStruct pytree of the block-paged decode cache: every KV
-    leaf becomes (L, num_blocks, block_size, heads...).  Position-indexed
-    KV families only — recurrent state has no sequence axis to page."""
+    leaf becomes (L, num_blocks, KV_heads, block_size[, head_dim]) — heads
+    outside the block, so one head's page is a contiguous (block_size,
+    head_dim) tile, the unit the paged-attention kernel reads.
+    Position-indexed KV families only — recurrent state has no sequence
+    axis to page."""
     if cfg.family not in ("dense", "moe", "vlm"):
         raise ValueError(f"no paged cache layout for family {cfg.family!r}")
     hd = cfg.resolved_head_dim
-    kv = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads, hd)
+    kv = (cfg.num_layers, num_blocks, cfg.num_kv_heads, block_size, hd)
     if cfg.kv_cache_int8:
-        sc = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads)
+        sc = (cfg.num_layers, num_blocks, cfg.num_kv_heads, block_size)
         return {"k": _sds(kv, jnp.int8), "k_scale": _sds(sc, jnp.float32),
                 "v": _sds(kv, jnp.int8), "v_scale": _sds(sc, jnp.float32)}
     return {"k": _sds(kv, DTYPE), "v": _sds(kv, DTYPE)}
@@ -300,12 +303,14 @@ def paged_insert(cfg: ArchConfig, pages, src_cache, block_ids, src_index=0):
     block_ids = jnp.asarray(block_ids, jnp.int32)
 
     def put(page, src):
-        # src (L, B, T, ...) -> row (L, T, ...) -> (L, P, bs, ...)
+        # src (L, B, T, KH, ...) -> row (L, T, KH, ...)
+        # -> (L, P, bs, KH, ...) -> page layout (L, P, KH, bs, ...)
         row = jax.lax.dynamic_index_in_dim(src, src_index, axis=1,
                                            keepdims=False)
         L, T = row.shape[0], row.shape[1]
         P = block_ids.shape[0]
         blocked = row.reshape(L, P, T // P, *row.shape[2:])
+        blocked = jnp.swapaxes(blocked, 2, 3)
         return page.at[:, block_ids].set(blocked.astype(page.dtype))
 
     return jax.tree.map(put, pages, src_cache)

@@ -169,11 +169,12 @@ def write_kv(cache, new, cache_len):
 
 
 def paged_write_kv(pages, new, block_ids, offsets):
-    """Write ``new`` (B, S, ...) into block-paged ``pages`` (N, bs, ...) at
-    per-(sequence, row) (physical block, in-block offset) positions, both
-    (B, S).  Inactive rows and speculative overhang past a slot's block
-    table target the trash block (id 0) — written, never read."""
-    return pages.at[block_ids, offsets].set(new.astype(pages.dtype))
+    """Write ``new`` (B, S, KH, ...) into block-paged ``pages``
+    (N, KH, bs, ...) at per-(sequence, row) (physical block, in-block
+    offset) positions, both (B, S).  Inactive rows and speculative overhang
+    past a slot's block table target the trash block (id 0) — written,
+    never read."""
+    return pages.at[block_ids, :, offsets].set(new.astype(pages.dtype))
 
 
 def paged_verify_attention(q, k_pages, v_pages, block_tables, cache_len, *,
@@ -184,9 +185,10 @@ def paged_verify_attention(q, k_pages, v_pages, block_tables, cache_len, *,
     one-query-row program, so the S > 1 verify path always takes the XLA
     gather formulation (it partitions under GSPMD on a mesh, like the
     paged-attention oracle)."""
+    from repro.kernels.paged_attention.ref import gather_pages
+
     def lin(p):
-        g = p[block_tables]                       # (B, P, bs, ...)
-        return g.reshape(g.shape[0], g.shape[1] * g.shape[2], *g.shape[3:])
+        return gather_pages(p, block_tables)
 
     return decode_attention(
         q, lin(k_pages), lin(v_pages), cache_len,
@@ -196,12 +198,11 @@ def paged_verify_attention(q, k_pages, v_pages, block_tables, cache_len, *,
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, cache_len, *,
                            k_scale=None, v_scale=None):
-    """q (B,1,H,D) against block-paged K/V (N, bs, KH, D) through per-slot
+    """q (B,1,H,D) against block-paged K/V (N, KH, bs, D) through per-slot
     block tables (B, P); positions <= cache_len valid, exactly as
     :func:`decode_attention`.  Dispatches to the Pallas paged-attention
     kernel / XLA gather oracle per the active matmul backend (under a mesh
-    trace the dispatch itself resolves to the oracle — pages are
-    replicated and the gather partitions under GSPMD)."""
+    the kernel runs inside ``shard_map`` — ``kernels/paged_attention/ops.py``)."""
     from repro.kernels.paged_attention.ops import paged_attention
     B, _, H, D = q.shape
     out = paged_attention(q[:, 0], k_pages, v_pages, block_tables,

@@ -297,7 +297,7 @@ def _paged_fns(batch):
     kernel / XLA oracle, S > 1 takes the dense-gather verify formulation."""
     cache_len = jnp.asarray(batch["cache_len"])
     tables = jnp.asarray(batch["block_tables"], jnp.int32)
-    bs = batch["cache"]["k"].shape[2]
+    bs = batch["cache"]["k"].shape[3]          # (L, N, KH, bs, D)
     S = batch["tokens"].shape[1]
     P = tables.shape[1]
     # physical write target per (slot, row): table entry at pos // bs
@@ -350,7 +350,7 @@ def decode_step_paged(params, cfg, batch):
     """One-token decode against a block-paged KV cache.
 
     batch: tokens (B,1); cache {k,v[,k_scale,v_scale]} with KV paged as
-    (L, num_blocks, block_size, KH, Dh); block_tables (B, P) int32 physical
+    (L, num_blocks, KH, block_size, Dh); block_tables (B, P) int32 physical
     page ids; cache_len (B,) int32 per-slot positions.  The new token's K/V
     is scattered to (table[pos // bs], pos % bs) per slot, and attention
     gathers through the block table (Pallas kernel / XLA oracle per the

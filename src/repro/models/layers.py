@@ -13,6 +13,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import quant
 from repro.core.bp_matmul import dense_apply
 
 DTYPE = jnp.bfloat16
@@ -75,11 +76,21 @@ def dense(params, x, mode="bf16"):
     return y
 
 
+@jax.jit
+def _quantize_kernel(w):
+    """(..., K, N) float -> int8 + per-output-channel scale (..., N).  One
+    fused program per leaf: the f32 view of the weight is never
+    materialized, and leaves quantize one at a time."""
+    w32 = w.astype(jnp.float32)
+    scale = quant.compute_scale(w32, axis=(w.ndim - 2,))
+    return (quant.quantize(w32, scale),
+            scale.reshape(w.shape[:-2] + (w.shape[-1],)))
+
+
 def quantize_dense_params(params):
     """Convert every dense kernel ("w", ndim>=2, float) to int8 + per-channel
     scale for weight-resident serving.  Embedding tables (gather-consumed)
     and 1D params are untouched."""
-    import jax
 
     def rec(node):
         if isinstance(node, dict):
@@ -95,11 +106,7 @@ def quantize_dense_params(params):
                     node["w_scale"] = jax.ShapeDtypeStruct(scale_shape,
                                                            jnp.float32)
                 else:
-                    from repro.core import quant
-                    scale = quant.compute_scale(w.astype(jnp.float32),
-                                                axis=(w.ndim - 2,))
-                    node["w"] = quant.quantize(w.astype(jnp.float32), scale)
-                    node["w_scale"] = scale.reshape(scale_shape)
+                    node["w"], node["w_scale"] = _quantize_kernel(w)
             return node
         return node
 

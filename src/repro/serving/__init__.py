@@ -17,8 +17,9 @@ from repro.serving.executor import (Executor, MeshExecutor,
                                     SingleDeviceExecutor, make_executor,
                                     make_serving_mesh)
 from repro.serving.faults import (NULL_INJECTOR, DeviceOOM, DrafterFault,
-                                  FaultInjector, InjectedFault, StepFault,
-                                  StepTimeout, TransientStepFault)
+                                  FaultInjector, InjectedFault,
+                                  StepCompileError, StepFault, StepTimeout,
+                                  TransientStepFault)
 from repro.serving.frontdoor import (FrontDoor, FrontDoorClient,
                                      FrontDoorServer, Replica, Router)
 from repro.serving.probe import (NULL_PROBE, PROBE_METHODS, SparsityProbe,
@@ -71,6 +72,7 @@ __all__ = [
     "SchedulerConfig",
     "SingleDeviceExecutor",
     "SparsityProbe",
+    "StepCompileError",
     "StepFault",
     "StepTimeout",
     "StreamSummary",

@@ -57,7 +57,8 @@ from repro.serving.block_pool import NoFreeBlocks, PagedCacheManager
 from repro.serving.cache_manager import make_cache_manager
 from repro.serving.executor import Executor, make_executor
 from repro.serving.faults import (NULL_INJECTOR, DrafterFault, FaultInjector,
-                                  InjectedFault, StepFault, StepTimeout)
+                                  InjectedFault, StepCompileError,
+                                  StepFault, StepTimeout)
 from repro.serving.probe import NULL_PROBE, SparsityProbe, probe_supported
 from repro.serving.queue import Request, RequestQueue, RequestState
 from repro.serving.scheduler import (QuasiSyncScheduler, SchedulerConfig,
@@ -659,13 +660,17 @@ class ServeLoop:
         """Fault boundary around one device dispatch: bounded retry with
         exponential backoff on injected transients (raised BEFORE the jit
         call, so the donated cache is untouched and a retry is safe), and
-        real executor failures wrapped into :class:`StepFault` so ``run()``
-        can tell recoverable infrastructure faults from plain bugs."""
+        runtime failures of an executed step (``JaxRuntimeError``) wrapped
+        into :class:`StepFault` so ``run()`` can tell recoverable
+        infrastructure faults from plain bugs.  A step that fails to
+        trace, lower or compile (:class:`StepCompileError`) and every other
+        exception propagate unwrapped: the run fails instead of retrying,
+        rebuilding or falling back to XLA."""
         attempt = 0
         while True:
             try:
                 return self._with_watchdog(fn)
-            except StepTimeout:
+            except (StepTimeout, StepCompileError):
                 raise
             except InjectedFault as e:
                 if attempt >= self.serve_cfg.max_step_retries:
@@ -677,7 +682,7 @@ class ServeLoop:
                 backoff = self.serve_cfg.retry_backoff_s
                 if backoff > 0:
                     time.sleep(backoff * 2 ** (attempt - 1))
-            except Exception as e:
+            except jax.errors.JaxRuntimeError as e:
                 raise StepFault(site, e) from e
 
     def _maybe_inject_nan(self, step: dict, slots: List[int]) -> None:

@@ -49,6 +49,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import threading
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
@@ -60,6 +61,7 @@ from repro.core import bp_matmul
 from repro.core import probe as core_probe
 from repro.distributed import sharding as shd
 from repro.models import api
+from repro.serving.faults import StepCompileError
 
 
 class Executor:
@@ -172,11 +174,21 @@ class Executor:
         wrap the traced body (entered only while tracing), call-time scopes
         wrap the dispatch.  The returned callable keeps a ``.lower``
         (scoped the same way) so tests can inspect the compiled HLO —
-        e.g. the donation/aliasing regression test."""
+        e.g. the donation/aliasing regression test.
+
+        An exception from a call that traced the program is a trace, lower
+        or compile failure and surfaces as :class:`StepCompileError`, which
+        the serve loop never recovers from.  Execution is asynchronous, so
+        runtime failures of an executed step surface later, where the
+        caller waits for the result."""
         trace_scopes = self._trace_scopes
+        # per-thread count of this program's traces: a call that moved it
+        # traced the program, so it also lowered and compiled it
+        traces = threading.local()
 
         @functools.wraps(fn)
         def traced(*args, **kwargs):
+            traces.n = getattr(traces, "n", 0) + 1
             with contextlib.ExitStack() as stack:
                 for ctx in trace_scopes():
                     stack.enter_context(ctx)
@@ -185,13 +197,16 @@ class Executor:
         jitted = jax.jit(traced, **jit_kwargs)
 
         def call(*args, **kwargs):
-            scopes = self._call_scopes()
-            if not scopes:
-                return jitted(*args, **kwargs)
-            with contextlib.ExitStack() as stack:
-                for ctx in scopes:
-                    stack.enter_context(ctx)
-                return jitted(*args, **kwargs)
+            n = getattr(traces, "n", 0)
+            try:
+                with contextlib.ExitStack() as stack:
+                    for ctx in self._call_scopes():
+                        stack.enter_context(ctx)
+                    return jitted(*args, **kwargs)
+            except Exception as e:
+                if getattr(traces, "n", 0) != n:
+                    raise StepCompileError(e) from e
+                raise
 
         def lower(*args, **kwargs):
             with contextlib.ExitStack() as stack:
@@ -500,7 +515,7 @@ class MeshExecutor(Executor):
     def _call_scopes(self):
         # mesh activation cannot happen inside a trace; the recipe/backend
         # thread-locals ride in the traced body (_trace_scopes)
-        return [shd.activate_mesh(self._mesh)]
+        return [jax.set_mesh(self._mesh)]
 
     def _place_params(self, params):
         shardings = shd.named_shardings(params, self.recipe_name, self._mesh)

@@ -86,6 +86,19 @@ class StepFault(RuntimeError):
         self.__cause__ = cause
 
 
+class StepCompileError(RuntimeError):
+    """A step failed while it was traced, lowered or compiled.
+
+    Deterministic: a retry, a rebuild or a switch to the XLA backend would
+    only hide the program that cannot be built for this device, so the
+    serve loop never recovers from it and the run fails.  Carries the
+    original exception as ``__cause__``."""
+
+    def __init__(self, cause: BaseException):
+        super().__init__(f"{type(cause).__name__}: {cause}")
+        self.__cause__ = cause
+
+
 class FaultInjector:
     """Deterministic fault source.
 

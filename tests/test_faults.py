@@ -16,7 +16,7 @@ from repro.configs.base import get_arch
 from repro.models import api
 from repro.serving import (NULL_INJECTOR, FaultInjector, Request,
                            RequestState, SchedulerConfig, ServeConfig,
-                           ServingEngine)
+                           ServingEngine, StepCompileError)
 
 jax.config.update("jax_default_matmul_precision", "float32")
 
@@ -207,8 +207,8 @@ def test_watchdog_aborts_stuck_step():
 
 
 def test_real_executor_failure_recovers_as_step_fault(monkeypatch):
-    # a genuine (non-injected) executor exception must be wrapped and
-    # survive via the same rebuild-and-replay path
+    # a genuine (non-injected) runtime failure of an executed step must be
+    # wrapped and survive via the same rebuild-and-replay path
     report_ref, loop, reqs = (None, None, None)
     engine = ServingEngine(CFG, _params(), ServeConfig(max_new_tokens=8))
     loop = engine.make_loop(_requests(), n_slots=2,
@@ -219,7 +219,7 @@ def test_real_executor_failure_recovers_as_step_fault(monkeypatch):
     def boom(*a, **k):
         if not state["fired"]:
             state["fired"] = True
-            raise ValueError("simulated XLA crash")
+            raise jax.errors.JaxRuntimeError("simulated XLA crash")
         return real_fn(*a, **k)
 
     loop._decode_fn = boom
@@ -229,8 +229,46 @@ def test_real_executor_failure_recovers_as_step_fault(monkeypatch):
     assert _tokens(report) == _baseline("slab", "none")
     # the real failure shows up as a non-injected fault record
     assert any(r["kind"] == "fault" and not r.get("injected")
-               and "ValueError" in r.get("error", "")
+               and "JaxRuntimeError" in r.get("error", "")
                for r in loop.stream)
+
+
+@pytest.mark.parametrize("error", [ValueError, jax.errors.JaxRuntimeError])
+def test_step_compile_failure_fails_the_run(error, monkeypatch):
+    """A step whose program fails to trace, lower or compile is not a
+    runtime fault: no retry, no rebuild, no XLA fallback — the run fails.
+    A JaxRuntimeError raised while the program is built (what a refused
+    kernel compile raises) counts as a compile failure too."""
+    def refuse(*a, **k):
+        raise error("simulated refusal while compiling the decode step")
+
+    monkeypatch.setattr(api, "decode_step", refuse)
+    engine = ServingEngine(CFG, _params(), ServeConfig(
+        max_new_tokens=8, kernel_fault_limit=1))
+    engine.executor.matmul_backend = "kernel_interpret"
+    loop = engine.make_loop(_requests(), n_slots=2,
+                            sched_cfg=SchedulerConfig(lead_window=2))
+    with pytest.raises(StepCompileError, match="simulated refusal"):
+        loop.run()
+    assert engine.executor.matmul_backend == "kernel_interpret"
+    assert not any(r["kind"] in ("fault", "recover", "degrade", "retry")
+                   for r in loop.stream)
+
+
+def test_non_runtime_dispatch_error_is_not_recovered():
+    """Only runtime failures of an executed step become StepFault: a plain
+    host-side exception inside a dispatch propagates unrecovered."""
+    engine = ServingEngine(CFG, _params(), ServeConfig(max_new_tokens=8))
+    loop = engine.make_loop(_requests(), n_slots=2,
+                            sched_cfg=SchedulerConfig(lead_window=2))
+
+    def bug(*a, **k):
+        raise ValueError("host-side bug")
+
+    loop._decode_fn = bug
+    with pytest.raises(ValueError, match="host-side bug"):
+        loop.run()
+    assert not any(r["kind"] == "recover" for r in loop.stream)
 
 
 # ---------------------------------------------------------------------------

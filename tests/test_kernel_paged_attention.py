@@ -22,8 +22,8 @@ def _case(seed, B, H, KH, D, n_blocks, bs, pages_per_seq, T_hi):
     table entries point at the trash page 0)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
-    kp = jax.random.normal(ks[1], (n_blocks, bs, KH, D), jnp.float32)
-    vp = jax.random.normal(ks[2], (n_blocks, bs, KH, D), jnp.float32)
+    kp = jax.random.normal(ks[1], (n_blocks, KH, bs, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (n_blocks, KH, bs, D), jnp.float32)
     rng = np.random.default_rng(seed)
     bt = np.zeros((B, pages_per_seq), np.int32)
     lengths = np.zeros(B, np.int32)
@@ -58,14 +58,17 @@ def test_kernel_matches_xla_oracle(B, H, KH, D, n_blocks, bs, pps, T_hi):
 def test_oracle_equals_dense_decode_attention():
     """Block paging is a pure storage transform: gathering the pages of a
     sequence and running the slab ``decode_attention`` must give the same
-    output as paged attention over the scattered pages."""
+    output as paged attention over the scattered pages.  The two programs
+    differ in shape (the oracle drops the query-row axis), so XLA may
+    reassociate the f32 softmax sums: equal to f32 rounding, not bitwise."""
     B, H, KH, D, n_blocks, bs, pps = 3, 4, 2, 16, 14, 4, 5
     q, kp, vp, bt, lens = _case(11, B, H, KH, D, n_blocks, bs, pps, 18)
     paged = paged_attention_xla(q, kp, vp, bt, lens)
     k_dense = gather_pages(kp, bt)     # (B, pps*bs, KH, D)
     v_dense = gather_pages(vp, bt)
     dense = decode_attention(q[:, None], k_dense, v_dense, lens)[:, 0]
-    np.testing.assert_array_equal(np.asarray(paged), np.asarray(dense))
+    np.testing.assert_allclose(np.asarray(paged), np.asarray(dense),
+                               rtol=1e-6, atol=1e-6)
 
 
 def test_int8_scale_pages_route_and_match():
@@ -76,9 +79,9 @@ def test_int8_scale_pages_route_and_match():
     kq = jnp.round(jnp.clip(kp * 20, -127, 127)).astype(jnp.int8)
     vq = jnp.round(jnp.clip(vp * 20, -127, 127)).astype(jnp.int8)
     ks = jnp.abs(jax.random.normal(jax.random.PRNGKey(3),
-                                   (n_blocks, bs, KH))) + 0.01
+                                   (n_blocks, KH, bs))) + 0.01
     vs = jnp.abs(jax.random.normal(jax.random.PRNGKey(4),
-                                   (n_blocks, bs, KH))) + 0.01
+                                   (n_blocks, KH, bs))) + 0.01
     with bp_matmul.use_matmul_backend("kernel_interpret"):
         got = paged_attention(q, kq, vq, bt, lens,
                               k_scale_pages=ks, v_scale_pages=vs)

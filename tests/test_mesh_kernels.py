@@ -43,7 +43,7 @@ def test_resolve_backend_keeps_kernel_under_mesh():
 
     mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
                 ("data", "model"))
-    with shd.activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         assert shd.current_mesh() is not None
         assert bpm.resolve_matmul_backend("kernel") == "kernel"
         assert bpm.resolve_matmul_backend("kernel_interpret") == \
@@ -68,9 +68,9 @@ def test_backend_fallback_ledger_counts_and_paged_scale_demotion():
         B, H, KH, D, bs, P = 2, 2, 1, 8, 4, 2
         N = 5
         q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(N, bs, KH, D)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(N, bs, KH, D)), jnp.float32)
-        ks = jnp.ones((N, bs, KH), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(N, KH, bs, D)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(N, KH, bs, D)), jnp.float32)
+        ks = jnp.ones((N, KH, bs), jnp.float32)
         bt = jnp.asarray(rng.integers(1, N, size=(B, P)), jnp.int32)
         ln = jnp.asarray(rng.integers(0, P * bs, size=(B,)), jnp.int32)
 
@@ -139,9 +139,9 @@ def test_mesh_matmul_kernel_parity_all_strategies():
             def f(x, w):
                 w_q, w_s = quant.quantize_per_channel(w, channel_axis=-1)
                 return quantized_matmul(x, w_q, w_s.reshape(-1), mode)
-            with shd.activate_mesh(mesh), bpm.use_matmul_backend("xla"):
+            with jax.set_mesh(mesh), bpm.use_matmul_backend("xla"):
                 ref = jax.jit(f)(x, w)
-            with shd.activate_mesh(mesh), \\
+            with jax.set_mesh(mesh), \\
                  bpm.use_matmul_backend("kernel_interpret"):
                 got = jax.jit(f)(x, w)
             np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)
@@ -158,7 +158,7 @@ def test_mesh_matmul_kernel_parity_all_strategies():
         sw = jnp.asarray(rng.random((n,)), jnp.float32)
         for approx in (False, True):
             single = bp_matmul(xq, wq, sa, sw, approx=approx, interpret=True)
-            with shd.activate_mesh(mesh):
+            with jax.set_mesh(mesh):
                 sharded = jax.jit(lambda *a: bp_matmul_sharded(
                     *a, approx=approx, interpret=True, mesh=mesh))(
                     xq, wq, sa, sw)
@@ -187,14 +187,14 @@ def test_mesh_paged_attention_kernel_parity():
     for (B, H, KH, D, bs, P) in cases:
         N = P * B + 1
         q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(N, bs, KH, D)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(N, bs, KH, D)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(N, KH, bs, D)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(N, KH, bs, D)), jnp.float32)
         bt = jnp.asarray(rng.integers(1, N, size=(B, P)), jnp.int32)
         ln = np.asarray(rng.integers(0, P * bs, size=(B,)), np.int32)
         ln[0] = 0                      # only the just-written token valid
         ln = jnp.asarray(ln)
         ref = paged_attention_xla(q, kp, vp, bt, ln)
-        with shd.activate_mesh(mesh), \\
+        with jax.set_mesh(mesh), \\
              bpm.use_matmul_backend("kernel_interpret"):
             got = jax.jit(paged_attention)(q, kp, vp, bt, ln)
         np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)

@@ -42,10 +42,9 @@ def test_sharded_train_step_matches_single_device():
                                               (4, 64), 0, 512)}
         ref, _ = api.loss_fn(params, cfg, batch)   # single device
 
-        # version-portable mesh activation (jax<0.5 and >=0.5 alike)
         mesh = Mesh(np.asarray(jax.devices()).reshape(2, 4),
                     ("data", "model"))
-        with shd.activate_mesh(mesh):
+        with jax.set_mesh(mesh):
             p_sh = shd.named_shardings(params, "train", mesh)
             params_s = jax.tree.map(jax.device_put, params, p_sh)
             b_sh = {"tokens": NamedSharding(mesh, P("data", None))}
@@ -115,7 +114,7 @@ def test_dryrun_cell_on_virtual_devices():
         cfg = get_arch("granite-moe-1b-a400m").reduced()
         mesh = Mesh(np.asarray(jax.devices()).reshape(2, 4),
                     ("data", "model"))
-        with shd.activate_mesh(mesh):
+        with jax.set_mesh(mesh):
             specs = {"tokens": jax.ShapeDtypeStruct((4, 128), jnp.int32)}
             p_specs = jax.eval_shape(partial(api.init, cfg=cfg),
                                      jax.random.PRNGKey(0))

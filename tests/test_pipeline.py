@@ -36,7 +36,6 @@ def test_pipeline_matches_sequential_and_is_differentiable():
         def stage(params, h):
             return jnp.tanh(h @ params)
 
-        # version-portable mesh construction (no AxisType on jax<0.5)
         mesh = jax.sharding.Mesh(np.asarray(jax.devices()), ("model",))
 
         def pipe(w, x):

@@ -1,0 +1,86 @@
+"""The main path's Pallas kernels compile for a TPU v5e at qwen2-1.5b widths.
+
+Each test compiles one kernel for a described (not attached) ``v5e:2x2``
+chip and asserts the compiled program holds the Mosaic kernel
+(``tpu_custom_call``).  Interpret-mode tests cannot see what the chip's
+compiler refuses (block tiling, VMEM use); these can, at no chip time.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this file.  The persistent compilation cache is off around the
+compiles (an entry written for a described chip cannot be read back).
+"""
+
+import os
+
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.bitparticle_matmul.ops import bp_matmul
+from repro.kernels.paged_attention.kernel import paged_attention_kernel
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("approx", [False, True], ids=["exact", "approx"])
+@pytest.mark.parametrize("m,k,n", [(8, 1536, 256), (512, 8960, 1536)],
+                         ids=["decode_kv_proj", "prefill_ffn_down"])
+def test_bp_matmul_compiles_for_v5e(one_chip, approx, m, k, n):
+    text = _compiled_text(
+        lambda a, w, sa, sw: bp_matmul(a, w, sa, sw, approx=approx),
+        one_chip, ((m, k), jnp.int8), ((k, n), jnp.int8),
+        ((m, 1), jnp.float32), ((n,), jnp.float32))
+    assert "tpu_custom_call" in text
+    assert f"bitparticle_matmul_{'approx' if approx else 'exact'}" in text
+
+
+@pytest.mark.parametrize("approx", [False, True], ids=["exact", "approx"])
+def test_bp_matmul_compiles_under_raised_default_precision(one_chip, approx):
+    """A process-wide ``jax_default_matmul_precision`` (tests and users set
+    it for f32 references) must not reach the kernel's int8 contraction:
+    the chip's compiler refuses an int8 matmul at a raised precision."""
+    with jax.default_matmul_precision("float32"):
+        text = _compiled_text(
+            lambda a, w, sa, sw: bp_matmul(a, w, sa, sw, approx=approx),
+            one_chip, ((8, 1536), jnp.int8), ((1536, 1536), jnp.int8),
+            ((8, 1), jnp.float32), ((1536,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("return_state", [False, True],
+                         ids=["normalized", "split_kv_state"])
+def test_paged_attention_compiles_for_v5e(one_chip, return_state):
+    B, KH, G, D, bs, pages = 8, 2, 6, 128, 16, 32
+    n_blocks = B * pages + 1
+    text = _compiled_text(
+        lambda *a: paged_attention_kernel(*a, return_state=return_state),
+        one_chip, ((B, KH * G, D), jnp.bfloat16),
+        ((n_blocks, KH, bs, D), jnp.bfloat16),
+        ((n_blocks, KH, bs, D), jnp.bfloat16),
+        ((B, pages), jnp.int32), ((B,), jnp.int32))
+    assert "tpu_custom_call" in text
+    assert "paged_attention_decode" in text
