@@ -1,0 +1,31 @@
+"""Published peaks per chip, keyed by ``device_kind`` as JAX reports it.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture):
+197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s per chip.
+A device that is not in the table is an error, never a default.
+"""
+
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "int8_ops": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "source": "cloud.google.com/tpu/docs/v5e",
+    },
+}
+
+
+class UnknownDevice(KeyError):
+    pass
+
+
+def lookup(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise UnknownDevice(
+            f"no peaks for device_kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}") from None
