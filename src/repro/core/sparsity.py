@@ -7,6 +7,7 @@ with 2's-complement, which exhibits lower sparsity for negative values.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.bitparticle import _popcount7, to_sign_magnitude
@@ -31,6 +32,15 @@ def bit_sparsity_sign_magnitude(q, nonzero_only: bool = False):
         m = (mag != 0).astype(jnp.float32)
         return jnp.sum(frac * m) / jnp.maximum(jnp.sum(m), 1.0)
     return jnp.mean(frac)
+
+
+#: :func:`bit_sparsity_sign_magnitude` of a whole tensor as ONE compiled
+#: reduction: XLA fuses the sign-magnitude split, the popcount and the mean,
+#: so no int32 or float32 temporary the size of the tensor is built (the
+#: eager form holds several at once, which a full-width weight stack cannot
+#: afford beside a KV pool)
+bit_sparsity_fused = jax.jit(bit_sparsity_sign_magnitude,
+                             static_argnames="nonzero_only")
 
 
 def popcount8(u):

@@ -90,8 +90,13 @@ class BaseCacheManager:
         """(n_slots,) per-slot positions for ``decode_step``.  Free slots sit
         at 0: their writes land in regions never read for an admitted
         request (overwritten by the next ``insert`` in the slab store,
-        pointed at the trash block in the paged store)."""
-        return jnp.asarray(self.lengths)
+        pointed at the trash block in the paged store).
+
+        A copy: on the CPU a device array may alias the host array it was
+        made from, and ``advance`` rewrites ``lengths`` in place while a
+        dispatched step may not have read it yet (the model drafter
+        advances before it waits on its step)."""
+        return jnp.asarray(self.lengths.copy())
 
     def divergence(self) -> int:
         """Spread of active-slot positions (the quasi-sync E analogue) —

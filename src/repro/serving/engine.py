@@ -63,7 +63,8 @@ from repro.serving.probe import NULL_PROBE, SparsityProbe, probe_supported
 from repro.serving.queue import Request, RequestQueue, RequestState
 from repro.serving.scheduler import (QuasiSyncScheduler, SchedulerConfig,
                                      prefill_bucket_len)
-from repro.serving.telemetry import (SCHEMA_VERSION, Telemetry, percentiles,
+from repro.serving.telemetry import (CLOCK_ORIGIN, SCHEMA_VERSION,
+                                     PhaseClock, Telemetry, percentiles,
                                      reduce_stream)
 
 #: errors the serve loop survives via rebuild-and-replay recovery: injected
@@ -282,7 +283,13 @@ class ServeLoop:
         # reduction over it whether or not any sink is attached — the
         # aggregate counters and the stream can never disagree
         self.stream: List[dict] = []
-        self._wall0 = time.perf_counter()
+        #: the clock origin of every record's ``ts_s``: the process's one
+        #: origin, shared with the ``Tracer``'s Chrome-trace ``ts``
+        self.clock_origin = CLOCK_ORIGIN
+        # the one timing path for the loop's host phases: always stamped,
+        # spans only when tracing is on; each step record takes what
+        # accumulated since the previous one in its ``phases``
+        self._phase = PhaseClock(self.tel)
         self._h2d_mark = int(self.tel.counters.get("h2d_bytes", 0))
         self._d2h_mark = int(self.tel.counters.get("d2h_bytes", 0))
         # fault injection rides the config exactly like telemetry; the
@@ -427,6 +434,11 @@ class ServeLoop:
                    block_size=int(self.serve_cfg.block_size),
                    probe_every=int(self.probe.probe_every))
 
+    @property
+    def _wall0(self) -> float:
+        """Former name of :attr:`clock_origin`."""
+        return self.clock_origin
+
     def _build_cm(self):
         return make_cache_manager(self.engine.cfg, self.n_slots,
                                   self._cache_T_arg,
@@ -464,13 +476,16 @@ class ServeLoop:
 
     # -- telemetry plumbing --------------------------------------------------
 
-    def _emit(self, kind: str, **fields) -> dict:
+    def _emit(self, kind: str, *, at: Optional[float] = None,
+              **fields) -> dict:
         """Append one record to the step stream and forward it to the
         metrics sink.  Values must already be native Python scalars — the
         JSONL line and the in-memory record are the SAME dict, which is
-        what makes the file reduction byte-equal to the live one."""
+        what makes the file reduction byte-equal to the live one.  ``at``
+        is the record's ``perf_counter`` stamp (now, by default)."""
+        at = time.perf_counter() if at is None else at
         rec = {"schema": SCHEMA_VERSION, "kind": kind,
-               "ts_s": time.perf_counter() - self._wall0}
+               "ts_s": at - self.clock_origin}
         rec.update(fields)
         self.stream.append(rec)
         self.tel.emit(rec)
@@ -571,17 +586,18 @@ class ServeLoop:
         whose wall-clock deadline passed.  Evicted actives free their slot
         and blocks immediately, so the very next admission plan sees the
         reclaimed capacity."""
-        if self.faults.enabled:
-            live = [int(r.request_id) for r in self._live_requests()]
-            self._cancel_ids.update(self.faults.cancel_requests(live))
-        pending = self.engine._pending_cancels
-        if pending:
-            self._cancel_ids.update(pending)
-            pending.clear()
-        if self._cancel_ids:
-            self._apply_cancels()
-        if self._any_deadlines:
-            self._apply_deadlines()
+        with self._phase("loop.sweep", "sweep_s"):
+            if self.faults.enabled:
+                live = [int(r.request_id) for r in self._live_requests()]
+                self._cancel_ids.update(self.faults.cancel_requests(live))
+            pending = self.engine._pending_cancels
+            if pending:
+                self._cancel_ids.update(pending)
+                pending.clear()
+            if self._cancel_ids:
+                self._apply_cancels()
+            if self._any_deadlines:
+                self._apply_deadlines()
 
     def _finish_evicted(self, req: Request, reason: str, kind: str,
                         where: str, **fields):
@@ -793,15 +809,10 @@ class ServeLoop:
                         "request; increase num_blocks")
                 self.preempt(victim)
 
-    def admit(self, group: List[Request], new_sync: bool = True):
-        """Fused prefill of one admission group: run the prompts, sample
-        (or replay) each request's first token, and install survivors into
-        slots.  ``new_sync`` marks the group as opening a fresh admission
-        sync in the metrics stream — ``run()`` passes True only for the
-        first group of each ``plan_admissions()`` batch, so the stream's
-        sync count matches the scheduler's."""
-        engine = self.engine
-        t_start = time.perf_counter()
+    def _stage_prefill(self, group: List[Request]):
+        """Host side of one admission group's prefill: mark the requests
+        admitted and build the padded token batch (plus extras).  Returns
+        ``(batch, lens, eff, pad_to)``."""
         wall_admit = time.perf_counter()
         for req in group:
             req.transition(RequestState.PREFILL)
@@ -843,8 +854,18 @@ class ServeLoop:
                     [np.asarray(extras[r.request_id][k]) for r in group])
         self.tel.count("h2d_bytes", sum(int(np.asarray(v).nbytes)
                                         for v in batch.values()))
-        t0 = time.perf_counter()
+        return batch, lens, eff, pad_to
 
+    def admit(self, group: List[Request], new_sync: bool = True):
+        """Fused prefill of one admission group: run the prompts, sample
+        (or replay) each request's first token, and install survivors into
+        slots.  ``new_sync`` marks the group as opening a fresh admission
+        sync in the metrics stream — ``run()`` passes True only for the
+        first group of each ``plan_admissions()`` batch, so the stream's
+        sync count matches the scheduler's."""
+        engine = self.engine
+        with self._phase("prefill.stage", "stage_s") as stage:
+            batch, lens, eff, pad_to = self._stage_prefill(group)
         probed = self.probe.enabled   # every admission prefill is sampled
 
         def dispatch():
@@ -857,16 +878,15 @@ class ServeLoop:
             out[0].block_until_ready()
             return out
 
-        with self.tel.span("prefill", group_size=len(group), pad_to=pad_to):
+        with self._phase("prefill", "dispatch_s", group_size=len(group),
+                         pad_to=pad_to) as ph:
             out = self._dispatch("prefill", dispatch)
         logits, cache = out[0], out[1]
         probe_stats = out[2] if probed else None
-        wall = time.perf_counter()
-        dispatch_s = wall - t0
-        self.prefill_s += dispatch_s
-        t_inst = time.perf_counter()
+        wall = ph.t1
+        self.prefill_s += ph.t1 - ph.t0
         n_emitted = 0
-        with self.tel.span("install", group_size=len(group)):
+        with self._phase("install", "install_s", group_size=len(group)):
             for j, req in enumerate(group):
                 chunked = eff[j] < req.prompt_len
                 tok = None
@@ -912,17 +932,17 @@ class ServeLoop:
                         engine._request_key_base(req))
                 if self.drafter is not None:
                     self.drafter.on_admit(slot, req)
-        install_s = time.perf_counter() - t_inst
         if probe_stats is not None:
             # the stats array is the probe's only d2h traffic: count it
             # BEFORE the byte snapshot so this record carries it
             probe_stats = np.asarray(probe_stats)
             self.tel.count("d2h_bytes", int(probe_stats.nbytes))
         h2d, d2h = self._byte_deltas()
-        self._emit("prefill", step=int(self.sched.n_decode_steps),
-                   wall_s=time.perf_counter() - t_start,
-                   phases={"dispatch_s": dispatch_s,
-                           "install_s": install_s},
+        gauges = self._pool_gauges()
+        end = time.perf_counter()
+        self._emit("prefill", at=end, step=int(self.sched.n_decode_steps),
+                   wall_s=end - stage.t0,
+                   phases=self._phase.take(end, "dispatch_s", "install_s"),
                    group_size=int(len(group)), pad_to=int(pad_to),
                    prompt_tokens=int(lens.sum()),
                    # every NON-CHUNKED request emits exactly one token at
@@ -932,8 +952,7 @@ class ServeLoop:
                    committed_tokens=int(n_emitted),
                    new_sync=bool(new_sync),
                    active_slots=int(self.cm.n_active),
-                   h2d_bytes=h2d, d2h_bytes=d2h,
-                   **self._pool_gauges())
+                   h2d_bytes=h2d, d2h_bytes=d2h, **gauges)
         if probe_stats is not None:
             self._emit_hw(probe_stats, "prefill",
                           n_tokens=int(lens.sum()) + n_emitted)
@@ -981,27 +1000,27 @@ class ServeLoop:
             slots = list(self.active.keys())
         return slots
 
-    def decode_once(self, slots: List[int], prepare_s: float = 0.0):
+    def decode_once(self, slots: List[int]):
         """One batched decode step: fixed (n_slots, ...) shapes, decode +
         fold + sample fused into ONE jitted dispatch with the cache buffer
-        donated; only the (n_slots,) sampled tokens transfer to host.
-        ``prepare_s`` is the caller-measured ``writable_slots`` wall (block
-        allocation / CoW on the paged store) for the step record."""
-        t_start = time.perf_counter()
-        counts = np.zeros(self.n_slots, np.uint32)
-        for s in slots:
-            counts[s] = len(self.active[s].tokens)
-        step = {"tokens": jnp.asarray(self.last_tok[:, None]),
-                "cache_len": self.cm.cache_len_vector()}
-        if self.paged:
-            step["block_tables"] = self.cm.block_tables_device()
-        self._maybe_inject_nan(step, slots)
-        self.tel.count("h2d_bytes",
-                       int(step["tokens"].nbytes)
-                       + int(step["cache_len"].nbytes)
-                       + int(self.slot_keys.nbytes) + int(counts.nbytes))
-        probed = self.probe.should_sample(int(self.sched.n_decode_steps))
-        t0 = time.perf_counter()
+        donated; only the (n_slots,) sampled tokens transfer to host.  The
+        record's ``prepare_s`` is the caller's ``decode.prepare`` phase
+        (``writable_slots``: block allocation / CoW on the paged store)."""
+        with self._phase("decode.stage", "stage_s") as stage:
+            counts = np.zeros(self.n_slots, np.uint32)
+            for s in slots:
+                counts[s] = len(self.active[s].tokens)
+            step = {"tokens": jnp.asarray(self.last_tok[:, None]),
+                    "cache_len": self.cm.cache_len_vector()}
+            if self.paged:
+                step["block_tables"] = self.cm.block_tables_device()
+            self._maybe_inject_nan(step, slots)
+            self.tel.count("h2d_bytes",
+                           int(step["tokens"].nbytes)
+                           + int(step["cache_len"].nbytes)
+                           + int(self.slot_keys.nbytes)
+                           + int(counts.nbytes))
+            probed = self.probe.should_sample(int(self.sched.n_decode_steps))
 
         def dispatch():
             fn = self._decode_probe_fn if probed else self._decode_fn
@@ -1010,27 +1029,25 @@ class ServeLoop:
             out[0].block_until_ready()
             return out
 
-        with self.tel.span("decode", n_slots=len(slots)):
+        with self._phase("decode", "dispatch_s", n_slots=len(slots)) as ph:
             out = self._dispatch("decode", dispatch)
-        toks, new_cache = out[0], out[1]
-        probe_stats = np.asarray(out[2]) if probed else None
-        wall = time.perf_counter()
-        dispatch_s = wall - t0
-        self.decode_s += dispatch_s
-        self.cm.update(new_cache)
-        self.cm.advance(slots)
-        self.sched.observe_decode_step(n_committed=len(slots))
-        # occupancy/divergence captured HERE (before finished slots free)
-        # so the record sees exactly what the scheduler observed
-        occupancy = self.cm.n_active / self.cm.n_slots
-        divergence = int(self.cm.divergence())
-        self.peak_active = max(self.peak_active, len(slots))
-        self.now += 1.0
-        toks_np = np.asarray(toks)
-        self.tel.count("d2h_bytes", int(toks_np.nbytes))
+        wall = ph.t1
+        self.decode_s += ph.t1 - ph.t0
         n_committed = 0
-        t_commit = time.perf_counter()
-        with self.tel.span("commit", n_slots=len(slots)):
+        with self._phase("commit", "commit_s", n_slots=len(slots)):
+            toks, new_cache = out[0], out[1]
+            probe_stats = np.asarray(out[2]) if probed else None
+            self.cm.update(new_cache)
+            self.cm.advance(slots)
+            self.sched.observe_decode_step(n_committed=len(slots))
+            # occupancy/divergence captured HERE (before finished slots
+            # free) so the record sees exactly what the scheduler observed
+            occupancy = self.cm.n_active / self.cm.n_slots
+            divergence = int(self.cm.divergence())
+            self.peak_active = max(self.peak_active, len(slots))
+            self.now += 1.0
+            toks_np = np.asarray(toks)
+            self.tel.count("d2h_bytes", int(toks_np.nbytes))
             for slot in slots:
                 req = self.active[slot]
                 if req.replay:
@@ -1054,7 +1071,6 @@ class ServeLoop:
                     del self.active[slot]
                     self.cm.free(slot)
                     req.finish(self.now, reason)
-        commit_s = time.perf_counter() - t_commit
         if n_committed != len(slots):
             # nan-guard failures committed nothing: correct the scheduler's
             # optimistic per-slot count (observed above, before the frees,
@@ -1063,16 +1079,16 @@ class ServeLoop:
         if probe_stats is not None:
             self.tel.count("d2h_bytes", int(probe_stats.nbytes))
         h2d, d2h = self._byte_deltas()
-        self._emit("decode", step=int(self.sched.n_decode_steps),
-                   wall_s=time.perf_counter() - t_start,
-                   phases={"prepare_s": float(prepare_s),
-                           "dispatch_s": dispatch_s,
-                           "commit_s": commit_s},
+        gauges = self._pool_gauges()
+        end = time.perf_counter()
+        self._emit("decode", at=end, step=int(self.sched.n_decode_steps),
+                   wall_s=end - stage.t0,
+                   phases=self._phase.take(end, "prepare_s", "dispatch_s",
+                                           "commit_s"),
                    active_slots=int(len(slots)), n_slots=int(self.n_slots),
                    occupancy=occupancy, divergence=divergence,
                    committed_tokens=int(n_committed),
-                   h2d_bytes=h2d, d2h_bytes=d2h,
-                   **self._pool_gauges())
+                   h2d_bytes=h2d, d2h_bytes=d2h, **gauges)
         if probe_stats is not None:
             self._emit_hw(probe_stats, "decode", n_tokens=n_committed)
 
@@ -1099,7 +1115,6 @@ class ServeLoop:
         (n_slots, K+1) for pure speculation, (n_slots, max(K+1, chunk))
         when chunk slots are aboard — so compiled variants stay O(1);
         causality makes the wider shape's extra garbage columns inert."""
-        t_start = time.perf_counter()
         # the drafter may be disabled mid-step by the degradation ladder;
         # slot bookkeeping below must keep using the one that drafted
         drafter = self.drafter
@@ -1111,9 +1126,8 @@ class ServeLoop:
         caps = {s: max(min(K, self.active[s].max_new_tokens
                            - len(self.active[s].tokens) - 1), 0)
                 for s in dec}
-        t_draft = time.perf_counter()
         drafts = {}
-        with self.tel.span("draft", n_slots=len(dec)):
+        with self._phase("draft", "draft_s", n_slots=len(dec)) as draft_ph:
             if drafter is not None and any(caps.values()):
                 try:
                     drafts = drafter.propose_all(
@@ -1131,7 +1145,6 @@ class ServeLoop:
                         self._emit("degrade",
                                    step=int(self.sched.n_decode_steps),
                                    action="disable_speculation")
-        draft_s = time.perf_counter() - t_draft
         drafts = {s: np.asarray(drafts.get(s, ()), np.int32)[:caps[s]]
                   for s in dec}
         # chunk rows: the next <= S unfed prompt tokens per chunk slot
@@ -1141,32 +1154,32 @@ class ServeLoop:
         # append span; preemption inside may shrink the slot set
         spans = {s: len(drafts[s]) + 1 for s in dec}
         spans.update({s: len(feeds[s]) for s in chunk_now})
-        t_prep = time.perf_counter()
-        slots = self.writable_slots(spans)
-        prepare_s = time.perf_counter() - t_prep
+        with self._phase("decode.prepare", "prepare_s"):
+            slots = self.writable_slots(spans)
         if not slots:
             return
-        # a preemption inside writable_slots evicts slots (and clears
-        # their chunk state): refresh both memberships before building rows
-        live = set(slots)
-        dec = [s for s in dec if s in live]
-        chunk_now = {s: p for s, p in chunk_now.items() if s in live}
-        toks = np.zeros((self.n_slots, S), np.int32)
-        for s in dec:
-            toks[s, 0] = self.last_tok[s]
-            d = drafts[s]
-            toks[s, 1:1 + len(d)] = d
-        for s in chunk_now:
-            toks[s, :len(feeds[s])] = feeds[s]
-        step = {"tokens": jnp.asarray(toks),
-                "cache_len": self.cm.cache_len_vector()}
-        if self.paged:
-            step["block_tables"] = self.cm.block_tables_device()
-        self._maybe_inject_nan(step, slots)
-        self.tel.count("h2d_bytes", int(step["tokens"].nbytes)
-                       + int(step["cache_len"].nbytes))
-        probed = self.probe.should_sample(int(self.sched.n_decode_steps))
-        t0 = time.perf_counter()
+        with self._phase("decode.stage", "stage_s"):
+            # a preemption inside writable_slots evicts slots (and clears
+            # their chunk state): refresh both memberships before building
+            # rows
+            live = set(slots)
+            dec = [s for s in dec if s in live]
+            chunk_now = {s: p for s, p in chunk_now.items() if s in live}
+            toks = np.zeros((self.n_slots, S), np.int32)
+            for s in dec:
+                toks[s, 0] = self.last_tok[s]
+                d = drafts[s]
+                toks[s, 1:1 + len(d)] = d
+            for s in chunk_now:
+                toks[s, :len(feeds[s])] = feeds[s]
+            step = {"tokens": jnp.asarray(toks),
+                    "cache_len": self.cm.cache_len_vector()}
+            if self.paged:
+                step["block_tables"] = self.cm.block_tables_device()
+            self._maybe_inject_nan(step, slots)
+            self.tel.count("h2d_bytes", int(step["tokens"].nbytes)
+                           + int(step["cache_len"].nbytes))
+            probed = self.probe.should_sample(int(self.sched.n_decode_steps))
 
         def dispatch():
             fn = self._verify_probe_fn if probed else self._verify_fn
@@ -1174,13 +1187,12 @@ class ServeLoop:
             out[0].block_until_ready()
             return out
 
-        with self.tel.span("verify", n_slots=len(slots)):
+        with self._phase("verify", "dispatch_s", n_slots=len(slots)) as ph:
             out = self._dispatch("verify", dispatch)
         greedy, new_cache = out[0], out[1]
         probe_stats = np.asarray(out[2]) if probed else None
-        wall = time.perf_counter()
-        dispatch_s = wall - t0
-        self.decode_s += dispatch_s
+        wall = ph.t1
+        self.decode_s += ph.t1 - ph.t0
         self.cm.update(new_cache)
         greedy_np = np.asarray(greedy)      # (n_slots, S) argmax stream
         self.tel.count("d2h_bytes", int(greedy_np.nbytes))
@@ -1189,8 +1201,7 @@ class ServeLoop:
         finished: Dict[int, str] = {}
         n_committed = 0                     # tokens EMITTED (decode output)
         n_chunk_fed = 0                     # prompt tokens fed (chunk rows)
-        t_commit = time.perf_counter()
-        with self.tel.span("commit", n_slots=len(slots)):
+        with self._phase("commit", "commit_s", n_slots=len(slots)):
             for slot in slots:
                 req = self.active[slot]
                 if slot in chunk_now:
@@ -1255,17 +1266,14 @@ class ServeLoop:
                         break
                 commits[slot] = appended
                 n_committed += appended
-        commit_s = time.perf_counter() - t_commit
         # commit the positions, then roll the paged store's speculative
         # tail blocks back BEFORE any slot is freed (free() releases whole
         # tables; release_tail only ever touches private draft-span blocks)
         self.cm.advance(slots, [commits[s] for s in slots])
-        t_rb = time.perf_counter()
         if self.paged:
-            with self.tel.span("rollback", n_slots=len(slots)):
+            with self._phase("rollback", "rollback_s", n_slots=len(slots)):
                 for slot in slots:
                     self.cm.release_tail(slot)
-        rollback_s = time.perf_counter() - t_rb
         self.sched.observe_decode_step(n_committed=n_committed)
         occupancy = self.cm.n_active / self.cm.n_slots
         divergence = int(self.cm.divergence())
@@ -1293,19 +1301,20 @@ class ServeLoop:
         if probe_stats is not None:
             self.tel.count("d2h_bytes", int(probe_stats.nbytes))
         h2d, d2h = self._byte_deltas()
-        self._emit("verify", step=int(self.sched.n_decode_steps),
-                   wall_s=time.perf_counter() - t_start,
-                   phases={"draft_s": draft_s, "prepare_s": prepare_s,
-                           "dispatch_s": dispatch_s, "commit_s": commit_s,
-                           "rollback_s": rollback_s},
+        gauges = self._pool_gauges()
+        end = time.perf_counter()
+        self._emit("verify", at=end, step=int(self.sched.n_decode_steps),
+                   wall_s=end - draft_ph.t0,
+                   phases=self._phase.take(end, "draft_s", "prepare_s",
+                                           "dispatch_s", "commit_s",
+                                           "rollback_s"),
                    active_slots=int(len(slots)), n_slots=int(self.n_slots),
                    occupancy=occupancy, divergence=divergence,
                    committed_tokens=int(n_committed),
                    chunk_tokens=int(n_chunk_fed),
                    drafted_tokens=int(self.n_drafted - drafted0),
                    accepted_tokens=int(self.n_accepted - accepted0),
-                   h2d_bytes=h2d, d2h_bytes=d2h,
-                   **self._pool_gauges())
+                   h2d_bytes=h2d, d2h_bytes=d2h, **gauges)
         if probe_stats is not None:
             self._emit_hw(probe_stats, "verify",
                           n_tokens=n_committed + n_chunk_fed)
@@ -1320,6 +1329,7 @@ class ServeLoop:
         with self._inbox_lock:
             if self._closed:
                 raise RuntimeError("serve loop is closed; cannot submit")
+            request.wall_enqueued_at = time.perf_counter()
             self._inbox.append(request)
 
     def close(self) -> None:
@@ -1329,18 +1339,21 @@ class ServeLoop:
             self._closed = True
 
     def _drain_inbox(self) -> None:
-        with self._inbox_lock:
-            if not self._inbox:
-                return
-            fresh, self._inbox = self._inbox, []
-        for req in fresh:
-            if req.arrival_time <= 0.0:
-                req.arrival_time = self.now
-            if (req.deadline_s is not None
-                    or req.ttft_deadline_s is not None):
-                self._any_deadlines = True
-            self.requests.append(req)
-            self.arrivals.append(req)
+        with self._phase("loop.inbox", "inbox_s"):
+            with self._inbox_lock:
+                if not self._inbox:
+                    return
+                fresh, self._inbox = self._inbox, []
+            drained = time.perf_counter()
+            for req in fresh:
+                req.wall_drained_at = drained
+                if req.arrival_time <= 0.0:
+                    req.arrival_time = self.now
+                if (req.deadline_s is not None
+                        or req.ttft_deadline_s is not None):
+                    self._any_deadlines = True
+                self.requests.append(req)
+                self.arrivals.append(req)
 
     def run(self) -> ServeReport:
         """Drain the constructor-supplied request list to completion (the
@@ -1357,33 +1370,32 @@ class ServeLoop:
         self.tel.start_profile()
         try:
             with self.tel.span("serve"):
-                self.submit_arrivals()
+                with self._phase("loop.notify", "notify_s"):
+                    self.submit_arrivals()
                 while True:
                     self._drain_inbox()
                     if not (self.arrivals or len(self.rq) or self.active):
-                        with self._inbox_lock:
-                            done = self._closed and not self._inbox
+                        with self._phase("loop.idle", "idle_s"):
+                            with self._inbox_lock:
+                                done = self._closed and not self._inbox
+                            if not done and poll_s > 0:
+                                time.sleep(poll_s)
                         if done:
                             break
-                        if poll_s > 0:
-                            time.sleep(poll_s)
                         continue
                     # lifecycle sweep first: cancellations/expiries free
                     # capacity that this iteration's admission plan sees
                     self.sweep()
-                    if not (self.arrivals or len(self.rq) or self.active):
-                        # the sweep may have terminalized the only work
-                        # (e.g. a cancel); observers still need to hear
-                        # about it even though no step will run
-                        if self.on_step_end is not None:
-                            self.on_step_end(self)
-                        continue
-                    try:
-                        self._step()
-                    except RECOVERABLE_ERRORS as e:
-                        self.recover(e)
+                    if (self.arrivals or len(self.rq) or self.active):
+                        try:
+                            self._step()
+                        except RECOVERABLE_ERRORS as e:
+                            self.recover(e)
+                    # else the sweep terminalized the only work (e.g. a
+                    # cancel): no step runs, but observers still hear of it
                     if self.on_step_end is not None:
-                        self.on_step_end(self)
+                        with self._phase("loop.notify", "notify_s"):
+                            self.on_step_end(self)
             self._emit_request_records()
             return self.report()
         finally:
@@ -1396,27 +1408,38 @@ class ServeLoop:
         percentiles, so ``reduce_stream`` over the JSONL file reproduces
         the report's numbers exactly (file/live parity)."""
         step = int(self.sched.n_decode_steps)
+
+        def gap(a: Optional[float], b: Optional[float]) -> Optional[float]:
+            return None if a is None or b is None else b - a
+
         for req in sorted(self.requests, key=lambda r: r.request_id):
             wt = req.wall_token_times
-            queue_wait = (None if req.wall_submitted_at is None
-                          or req.wall_admitted_at is None
-                          else req.wall_admitted_at - req.wall_submitted_at)
-            ttft_wall = (None if req.wall_submitted_at is None or not wt
-                         else wt[0] - req.wall_submitted_at)
+            # a copy: the front door may still be writing the last tokens
+            written = list(req.wall_written_times)
             self._emit("request", step=step,
                        request_id=int(req.request_id),
                        slo_class=str(req.slo_class),
                        finish_reason=req.finish_reason,
                        n_tokens=int(len(req.tokens)),
-                       queue_wait_s=queue_wait,
-                       ttft_wall_s=ttft_wall,
-                       itl_wall_s=[b - a for a, b in zip(wt, wt[1:])])
+                       queue_wait_s=gap(req.wall_submitted_at,
+                                        req.wall_admitted_at),
+                       ttft_wall_s=gap(req.wall_submitted_at,
+                                       wt[0] if wt else None),
+                       itl_wall_s=[b - a for a, b in zip(wt, wt[1:])],
+                       ingress_s=gap(req.wall_received_at,
+                                     req.wall_enqueued_at),
+                       inbox_s=gap(req.wall_enqueued_at,
+                                   req.wall_drained_at),
+                       hold_s=gap(req.wall_drained_at,
+                                  req.wall_submitted_at),
+                       egress_s=[w - t for t, w in zip(wt, written)])
 
     def _step(self):
         """One loop iteration: admissions, then one batched decode/verify.
         Raising out of here with a RECOVERABLE error leaves no partial
         state — failed admissions are rolled back to the queue head."""
-        groups = self.sched.plan_admissions()
+        with self._phase("loop.plan", "plan_s"):
+            groups = self.sched.plan_admissions()
         try:
             # one plan_admissions() batch is ONE admission sync;
             # only its first group opens the sync in the stream
@@ -1429,18 +1452,19 @@ class ServeLoop:
             if not len(self.rq) and self.arrivals:
                 # idle: jump the virtual clock to the next arrival
                 self.now = max(self.now, self.arrivals[0].arrival_time)
-                self.submit_arrivals()
+                with self._phase("loop.notify", "notify_s"):
+                    self.submit_arrivals()
             return
         if self.drafter is not None or self.chunking:
             self.decode_once_spec()
         else:
-            t_prep = time.perf_counter()
-            slots = self.writable_slots()
-            prepare_s = time.perf_counter() - t_prep
+            with self._phase("decode.prepare", "prepare_s"):
+                slots = self.writable_slots()
             if not slots:
                 return
-            self.decode_once(slots, prepare_s=prepare_s)
-        self.submit_arrivals()
+            self.decode_once(slots)
+        with self._phase("loop.notify", "notify_s"):
+            self.submit_arrivals()
 
     def _rollback_admissions(self, groups: List[List[Request]]):
         """A recoverable fault escaped mid-admission: return every
@@ -1812,14 +1836,16 @@ class ServingEngine:
     def deployment_estimate(self, n_mc: int = 20_000) -> Optional[dict]:
         """Per-layer modeled cycles/energy of the quantized weights on the
         BitParticle array (None unless a bp_* matmul mode is active).
-        Cached: it depends only on the immutable params."""
+        Cached: it depends only on the immutable params.  Each weight
+        slice's bit sparsity is one compiled reduction, so no int32 or
+        float32 copy of a slice is built on the device."""
         mode = self.cfg.matmul_mode
         if mode not in ("bp_exact", "bp_approx"):
             return None
         if n_mc in self._deployment_cache:
             return self._deployment_cache[n_mc]
         from repro.core import cost_model as cost
-        from repro.core.sparsity import bit_sparsity_sign_magnitude
+        from repro.core.sparsity import bit_sparsity_fused
 
         L = self.cfg.num_layers
         per_layer_bs: Dict[int, List[float]] = {}
@@ -1829,10 +1855,10 @@ class ServingEngine:
             if leaf.ndim >= 2 and leaf.shape[0] == L:
                 for l in range(L):
                     per_layer_bs.setdefault(l, []).append(
-                        float(bit_sparsity_sign_magnitude(leaf[l])))
+                        float(bit_sparsity_fused(leaf[l])))
             else:
                 per_layer_bs.setdefault(-1, []).append(
-                    float(bit_sparsity_sign_magnitude(leaf)))
+                    float(bit_sparsity_fused(leaf)))
         if not per_layer_bs:
             return None
         layers = []

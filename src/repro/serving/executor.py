@@ -250,7 +250,9 @@ class Executor:
         if probed:
             self._require_probe_support()
 
-        def run(p, b, t, lens=None):
+        # the jitted function's name is the program's name in a device
+        # trace (``jit_prefill_step``): keep it stable
+        def prefill_step(p, b, t, lens=None):
             if not probed:
                 return api.prefill(p, cfg, b, t, prompt_lens=lens)
             with core_probe.probe_tap():
@@ -260,10 +262,10 @@ class Executor:
 
         if prompt_lens is None:
             fn = self._get(("prefill", bool(probed)), lambda: self._jit(
-                lambda p, b, t: run(p, b, t), static_argnums=(2,)))
+                prefill_step, static_argnums=(2,)))
             return fn(self._params, batch, cache_T)
         fn = self._get(("prefill_ragged", bool(probed)), lambda: self._jit(
-            lambda p, b, t, lens: run(p, b, t, lens), static_argnums=(2,)))
+            prefill_step, static_argnums=(2,)))
         return fn(self._params, batch, cache_T, jnp.asarray(prompt_lens))
 
     def decode_step(self, step):
@@ -294,7 +296,8 @@ class Executor:
         def build():
             decode = api.decode_step_paged if paged else api.decode_step
 
-            def step_fn(p, cache, step, keys, counts):
+            # named for the device trace (``jit_decode_step``)
+            def decode_step(p, cache, step, keys, counts):
                 step = dict(step, cache=cache)
                 # optional fault-injection mask (n_slots,) bool: NaN the
                 # whole logit row for flagged slots (exercises the guard)
@@ -324,7 +327,7 @@ class Executor:
                     return tok.astype(jnp.int32), new_cache, stats
                 return tok.astype(jnp.int32), new_cache
 
-            jitted = self._jit(step_fn, donate_argnums=(1,))
+            jitted = self._jit(decode_step, donate_argnums=(1,))
 
             def fn(cache, step, keys, counts):
                 self.faults.check("step")
@@ -356,7 +359,8 @@ class Executor:
         def build():
             verify = api.verify_step_paged if paged else api.verify_step
 
-            def step_fn(p, cache, step):
+            # named for the device trace (``jit_verify_step``)
+            def verify_step(p, cache, step):
                 step = dict(step, cache=cache)
                 nan_mask = step.pop("nan_mask", None)
                 with contextlib.ExitStack() as tap:
@@ -376,7 +380,7 @@ class Executor:
                     return tok.astype(jnp.int32), new_cache, stats
                 return tok.astype(jnp.int32), new_cache
 
-            jitted = self._jit(step_fn, donate_argnums=(1,))
+            jitted = self._jit(verify_step, donate_argnums=(1,))
 
             def fn(cache, step):
                 self.faults.check("step")

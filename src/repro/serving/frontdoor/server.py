@@ -25,6 +25,14 @@ Responses are ``Connection: close`` framed (body ends when the socket
 does) — no chunked encoding, so the tiny test client stays a plain
 socket reader.
 
+Timing: ``/v1/generate`` stamps the request when its head has been read
+(``Request.wall_received_at``) and after each SSE token event's
+``drain()`` returns (``wall_written_times``).  Its spans go to the routed
+replica's ``Telemetry`` on this thread's track: ``http.parse`` (body
+decode, JSON parse, ``Request`` build) and ``route`` (``router.submit``)
+from stamps once the replica is known, ``sse.write`` (one event's
+synchronous write) live.  No span wraps an ``await``.
+
 :class:`FrontDoor` bundles replicas + router + server and runs the
 asyncio event loop on a background thread, giving tests and benchmarks a
 synchronous ``start()``/``stop()`` surface.
@@ -35,6 +43,7 @@ from __future__ import annotations
 import asyncio
 import json
 import threading
+import time
 from typing import Optional, Sequence
 
 import numpy as np
@@ -98,13 +107,15 @@ class FrontDoorServer:
                       writer: asyncio.StreamWriter):
         try:
             try:
-                method, path, body = await self._read_request(reader)
+                method, path, body, received = await self._read_request(
+                    reader)
             except HttpError as e:
                 writer.write(_json_response(e.status, {"error": e.message}))
                 await writer.drain()
                 return
             try:
-                await self._dispatch(method, path, body, reader, writer)
+                await self._dispatch(method, path, body, reader, writer,
+                                     received)
             except HttpError as e:
                 writer.write(_json_response(e.status, {"error": e.message}))
                 await writer.drain()
@@ -118,11 +129,14 @@ class FrontDoorServer:
                 pass
 
     async def _read_request(self, reader):
+        """``(method, path, body, received)``: ``received`` is the
+        ``perf_counter`` stamp taken once the head has been read."""
         try:
             head = await reader.readuntil(b"\r\n\r\n")
         except asyncio.LimitOverrunError:
             raise HttpError("431 Request Header Fields Too Large",
                             "header block too large")
+        received = time.perf_counter()
         if len(head) > _MAX_HEADER:
             raise HttpError("431 Request Header Fields Too Large",
                             "header block too large")
@@ -140,9 +154,9 @@ class FrontDoorServer:
         if length > _MAX_BODY:
             raise HttpError("413 Payload Too Large", "body too large")
         body = await reader.readexactly(length) if length else b""
-        return method.upper(), path, body
+        return method.upper(), path, body, received
 
-    async def _dispatch(self, method, path, body, reader, writer):
+    async def _dispatch(self, method, path, body, reader, writer, received):
         if method == "GET" and path == "/healthz":
             writer.write(_json_response("200 OK", {"ok": True}))
             await writer.drain()
@@ -150,7 +164,7 @@ class FrontDoorServer:
             writer.write(_json_response("200 OK", self.router.stats()))
             await writer.drain()
         elif method == "POST" and path == "/v1/generate":
-            await self._generate(body, reader, writer)
+            await self._generate(body, reader, writer, received)
         else:
             raise HttpError("404 Not Found", f"no route {method} {path}")
 
@@ -168,7 +182,8 @@ class FrontDoorServer:
                             "prompt must be a non-empty list of ints")
         return obj
 
-    async def _generate(self, body, reader, writer):
+    async def _generate(self, body, reader, writer, received):
+        t_parse = time.perf_counter()
         obj = self._parse_generate(body)
         aloop = asyncio.get_running_loop()
         events: asyncio.Queue = asyncio.Queue()
@@ -194,13 +209,20 @@ class FrontDoorServer:
             slo_class=str(obj.get("slo_class", "default")),
             deadline_s=obj.get("deadline_s"),
             ttft_deadline_s=obj.get("ttft_deadline_s"))
+        request.wall_received_at = received
         tokens = []
+        t_route = time.perf_counter()
         try:
             replica, rid = self.router.submit(
                 request, on_token=on_token if stream else None,
                 on_finish=on_finish)
         except (RuntimeError, ValueError) as e:
             raise HttpError("503 Service Unavailable", str(e))
+        t_routed = time.perf_counter()
+        tel = replica.loop.tel
+        tel.record_span("http.parse", t_parse, t_route)
+        tel.record_span("route", t_route, t_routed, request_id=rid,
+                        replica=replica.name)
 
         if not stream:
             done = await events.get()
@@ -234,10 +256,13 @@ class FrontDoorServer:
                 event.setdefault("request_id", rid)
                 event.setdefault("replica", replica.name)
                 try:
-                    writer.write(_sse_event(event))
+                    with tel.span("sse.write"):
+                        writer.write(_sse_event(event))
                     await writer.drain()
                 except (ConnectionError, OSError):
                     raise ConnectionResetError("client disconnected")
+                if "token" in event:
+                    request.wall_written_times.append(time.perf_counter())
                 if event.get("done"):
                     return
         except ConnectionResetError:

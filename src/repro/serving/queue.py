@@ -113,6 +113,15 @@ class Request:
     wall_submitted_at: Optional[float] = None
     wall_admitted_at: Optional[float] = None
     wall_token_times: List[float] = dataclasses.field(default_factory=list)
+    # the path before the waiting queue and after the commit, in order:
+    # the front door read the request head; ``ServeLoop.submit`` put it in
+    # the inbox; the loop drained it into its arrivals; and, per token, the
+    # front door's SSE write of it returned (None / [] where the request
+    # did not take that path, e.g. a batch ``serve()`` call)
+    wall_received_at: Optional[float] = None
+    wall_enqueued_at: Optional[float] = None
+    wall_drained_at: Optional[float] = None
+    wall_written_times: List[float] = dataclasses.field(default_factory=list)
 
     def __post_init__(self):
         self.prompt = np.asarray(self.prompt, np.int32).reshape(-1)

@@ -16,10 +16,14 @@ pieces fix that:
     (``benchmarks/compare.py``).
   * :class:`Tracer` — Chrome-trace-event JSON ("X" complete events) around
     admission, prefill, draft, verify, commit, preemption, and block-pool
-    operations.  The file loads directly in https://ui.perfetto.dev (or
-    ``chrome://tracing``).  With ``annotate_device=True`` every span also
-    enters a ``jax.profiler.TraceAnnotation`` so host spans line up with
-    device traces captured via ``profile_dir``.
+    operations, each on its own thread's ``tid``.  The file loads directly
+    in https://ui.perfetto.dev (or ``chrome://tracing``).  With
+    ``annotate_device=True`` every span also enters a
+    ``jax.profiler.TraceAnnotation`` so host spans line up with device
+    traces captured via ``profile_dir``.
+  * :class:`PhaseClock` — the serve loop's one timing path for its host
+    phases: it stamps every phase, always, and exports it as a span only
+    when tracing is on.
   * :func:`reduce_stream` — the PURE reduction from step records to the
     ``ServeReport`` aggregates.  ``ServeLoop.report()`` calls exactly this
     over exactly the records it emitted, so the aggregate counters and the
@@ -42,6 +46,7 @@ import contextlib
 import dataclasses
 import json
 import os
+import threading
 import time
 from typing import Dict, List, Optional
 
@@ -96,10 +101,21 @@ STEP_SCHEMA: Dict[str, set] = {
     # queue entry to first token, ``itl_wall_s`` the request's pairwise
     # inter-token gaps — the raw samples behind the report's per-SLO-class
     # percentiles, so the file reduction reproduces them exactly
+    # ``ingress_s`` / ``inbox_s`` / ``hold_s`` (additive) split the time
+    # before queue entry: front door read the head -> loop inbox -> drained
+    # into the arrivals -> waiting queue; ``egress_s`` is, per token written
+    # to the client, write done - commit (None / [] where a stamp is
+    # missing, as for requests that never crossed the front door)
     "request": {"schema", "kind", "ts_s", "step", "request_id", "slo_class",
                 "finish_reason", "n_tokens", "queue_wait_s", "ttft_wall_s",
-                "itl_wall_s"},
+                "itl_wall_s", "ingress_s", "inbox_s", "hold_s", "egress_s"},
 }
+
+#: The process's one clock origin (``time.perf_counter`` seconds).  A step
+#: record's ``ts_s`` and a Chrome-trace event's ``ts`` (in µs) count from
+#: it, so they and the ``perf_counter`` instant of any profiler
+#: ``TraceAnnotation`` convert to one another with one offset.
+CLOCK_ORIGIN = time.perf_counter()
 
 
 def percentiles(samples, qs=(50, 90, 99)) -> Optional[Dict[str, float]]:
@@ -165,75 +181,199 @@ class MetricsLogger:
 
 
 class Tracer:
-    """Chrome-trace-event recorder (complete "X" events, µs timestamps).
+    """Chrome-trace-event recorder (complete "X" events, µs timestamps on
+    :data:`CLOCK_ORIGIN`).
 
-    Spans nest by construction: events are emitted on one host thread with
-    monotonic ``time.perf_counter`` stamps, so a child span is always fully
-    contained in its parent — the property ``tests/test_telemetry.py``
-    checks on the written file.  ``write()`` dumps the standard
+    Each event carries its thread's ``tid`` (with a ``thread_name``
+    metadata event the first time a thread records), so spans nest per
+    thread: the serve loop's worker and the front door's asyncio thread
+    each get a track.  ``write()`` dumps the standard
     ``{"traceEvents": [...]}`` wrapper that perfetto / chrome://tracing
     load directly.
+
+    ``keep_events=False`` keeps no event list (nothing would write it):
+    spans then only enter ``jax.profiler.TraceAnnotation`` when
+    ``annotate_device`` is on, and ``write`` has nothing to dump.
     """
 
-    def __init__(self, *, annotate_device: bool = False):
-        self.events: List[dict] = []
-        self.annotate_device = annotate_device
-        self._t0 = time.perf_counter()
+    def __init__(self, *, annotate_device: bool = False,
+                 keep_events: bool = True):
+        self.events: Optional[List[dict]] = [] if keep_events else None
         self._pid = os.getpid()
-        self.events.append({
-            "name": "process_name", "ph": "M", "pid": self._pid, "tid": 0,
-            "args": {"name": "repro.serving"},
-        })
+        self._annotation = None
+        if annotate_device:
+            try:
+                import jax
+                self._annotation = jax.profiler.TraceAnnotation
+            except ImportError:   # profiler unavailable: host spans work
+                pass
+        self._tids: set = set()
+        self._tid_lock = threading.Lock()
+        if self.events is not None:
+            self.events.append({
+                "name": "process_name", "ph": "M", "pid": self._pid,
+                "tid": 0, "args": {"name": "repro.serving"},
+            })
 
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+    @staticmethod
+    def _us(t: float) -> float:
+        return (t - CLOCK_ORIGIN) * 1e6
+
+    def _tid(self) -> int:
+        tid = threading.get_native_id()
+        if tid not in self._tids:
+            with self._tid_lock:
+                if tid not in self._tids:
+                    self._tids.add(tid)
+                    self.events.append({
+                        "name": "thread_name", "ph": "M", "pid": self._pid,
+                        "tid": tid,
+                        "args": {"name": threading.current_thread().name},
+                    })
+        return tid
+
+    def annotate(self, name: str):
+        """Enter a profiler annotation named ``name`` (None when device
+        annotation is off); the caller exits it."""
+        if self._annotation is None:
+            return None
+        ann = self._annotation(name)
+        ann.__enter__()
+        return ann
+
+    def complete(self, name: str, t0: float, t1: float,
+                 cat: str = "serving", **args):
+        """Record one span from ``perf_counter`` stamps already taken, on
+        the calling thread's track."""
+        if self.events is None:
+            return
+        self.events.append({
+            "name": name, "cat": cat, "ph": "X", "ts": self._us(t0),
+            "dur": (t1 - t0) * 1e6, "pid": self._pid, "tid": self._tid(),
+            "args": {k: _jsonable(v) for k, v in args.items()},
+        })
 
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "serving", **args):
-        ann = None
-        if self.annotate_device:
-            try:
-                import jax
-                ann = jax.profiler.TraceAnnotation(name)
-                ann.__enter__()
-            except Exception:   # profiler unavailable: host span still works
-                ann = None
-        t0 = self._now_us()
+        ann = self.annotate(name)
+        t0 = time.perf_counter()
         try:
             yield self
         finally:
-            t1 = self._now_us()
+            t1 = time.perf_counter()
             if ann is not None:
                 ann.__exit__(None, None, None)
-            self.events.append({
-                "name": name, "cat": cat, "ph": "X", "ts": t0,
-                "dur": t1 - t0, "pid": self._pid, "tid": 0,
-                "args": {k: _jsonable(v) for k, v in args.items()},
-            })
+            self.complete(name, t0, t1, cat, **args)
 
     def instant(self, name: str, cat: str = "serving", **args):
+        if self.events is None:
+            return
         self.events.append({
             "name": name, "cat": cat, "ph": "i", "s": "t",
-            "ts": self._now_us(), "pid": self._pid, "tid": 0,
+            "ts": self._us(time.perf_counter()), "pid": self._pid,
+            "tid": self._tid(),
             "args": {k: _jsonable(v) for k, v in args.items()},
         })
 
     def counter(self, name: str, **values):
         """Chrome-trace counter ("C") sample: perfetto renders one stacked
         counter track named ``name`` with a series per kwarg."""
+        if self.events is None:
+            return
         self.events.append({
-            "name": name, "ph": "C", "ts": self._now_us(),
-            "pid": self._pid, "tid": 0,
+            "name": name, "ph": "C", "ts": self._us(time.perf_counter()),
+            "pid": self._pid, "tid": self._tid(),
             "args": {k: _jsonable(v) for k, v in values.items()},
         })
 
     def write(self, path: str):
+        if self.events is None:
+            return
         d = os.path.dirname(os.path.abspath(path))
         if d:
             os.makedirs(d, exist_ok=True)
         with open(path, "w", encoding="utf-8") as f:
-            json.dump({"traceEvents": self.events,
+            # a copy: another thread (the front door) may still append
+            json.dump({"traceEvents": list(self.events),
                        "displayTimeUnit": "ms"}, f, default=float)
+
+
+class Phase:
+    """One timed host phase of the serve loop (see :class:`PhaseClock`).
+    After the ``with`` block, ``t0`` / ``t1`` hold its ``perf_counter``
+    stamps."""
+
+    __slots__ = ("_clock", "name", "key", "_args", "_ann", "t0", "t1")
+
+    def __init__(self, clock: "PhaseClock", name: str, key: str,
+                 args: dict):
+        self._clock = clock
+        self.name = name
+        self.key = key
+        self._args = args
+        self._ann = None
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "Phase":
+        clock = self._clock
+        tracer = clock.tel.tracer
+        if tracer is not None:
+            self._ann = tracer.annotate(self.name)
+        self.t0 = t0 = time.perf_counter()
+        clock.add(OTHER_PHASE, t0 - clock.mark)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        clock = self._clock
+        self.t1 = clock.mark = t1 = time.perf_counter()
+        clock.add(self.key, t1 - self.t0)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        tracer = clock.tel.tracer
+        if tracer is not None:
+            tracer.complete(self.name, self.t0, t1, **self._args)
+        return False
+
+
+#: the ``phases`` key of host time spent outside every named phase
+#: (record emission, recovery, the few statements between phases)
+OTHER_PHASE = "other_s"
+
+
+class PhaseClock:
+    """The serve loop's one timing path for its host phases.
+
+    ``with clock("decode.stage", "stage_s"):`` stamps the block with
+    ``perf_counter``, always, and adds its duration under ``"stage_s"``
+    to what the next step record takes (:meth:`take`).  Only when tracing
+    is on does the phase also become a span (a Chrome-trace event and, with
+    ``annotate_device``, a profiler annotation) named ``"decode.stage"``,
+    from the same two stamps.  Time between phases goes to
+    :data:`OTHER_PHASE`, so the durations one record takes partition the
+    time since the previous record's stamp exactly.  Phases do not nest;
+    the clock is used by one thread, the loop's."""
+
+    def __init__(self, tel: "Telemetry"):
+        self.tel = tel
+        self.acc: Dict[str, float] = {}
+        self.mark = time.perf_counter()   # end of the last phase or take
+
+    def __call__(self, name: str, key: str, **args) -> Phase:
+        return Phase(self, name, key, args)
+
+    def add(self, key: str, seconds: float) -> None:
+        self.acc[key] = self.acc.get(key, 0.0) + seconds
+
+    def take(self, now: float, *pinned: str) -> Dict[str, float]:
+        """The durations accumulated since the last call, up to ``now``
+        (a ``perf_counter`` stamp, the record's own), with every ``pinned``
+        key present (0.0 where its phase did not run)."""
+        self.add(OTHER_PHASE, now - self.mark)
+        self.mark = now
+        out = dict.fromkeys(pinned, 0.0)
+        out.update(self.acc)
+        self.acc = {}
+        return out
 
 
 def _jsonable(v):
@@ -262,7 +402,8 @@ class Telemetry:
                  annotate_device: bool = False):
         self.metrics = MetricsLogger(metrics_path) if metrics_path else None
         self.trace_path = trace_path
-        self.tracer = (Tracer(annotate_device=annotate_device)
+        self.tracer = (Tracer(annotate_device=annotate_device,
+                              keep_events=bool(trace_path))
                        if (trace_path or annotate_device) else None)
         self.profile_dir = profile_dir
         self.counters: Dict[str, int] = {"h2d_bytes": 0, "d2h_bytes": 0}
@@ -279,6 +420,15 @@ class Telemetry:
         if self.tracer is None:
             return NULL_SPAN
         return self.tracer.span(name, **args)
+
+    def record_span(self, name: str, t0: float, t1: float, **args):
+        """A span from ``perf_counter`` stamps taken earlier, for work that
+        ran before it was known which handle records it (the front door
+        parses and routes a request before it knows the replica).  It
+        reaches the Chrome trace only: a profiler annotation cannot be
+        entered after the fact."""
+        if self.tracer is not None:
+            self.tracer.complete(name, t0, t1, **args)
 
     def instant(self, name: str, **args):
         if self.tracer is not None:
