@@ -14,7 +14,7 @@ line is printed.
     against ``core.bp_matmul.bp_matmul_int`` on XLA: int32 accumulators
     equal, dequantized output within ``MATMUL_RTOL``.  The paged-attention
     kernel against its XLA gather oracle (bf16 pages, block 16, G=6, D=128,
-    ragged lengths) within ``ATTN_ATOL``.
+    ragged lengths, layer 1 of a two-layer pool) within ``ATTN_ATOL``.
 (c) The main path.  qwen2-1.5b at its published widths (28 layers, random
     weights from ``--seed``), quantized to int8, ``bp_exact``, matmul
     backend ``auto``, paged KV cache with float pages and block 16.  One
@@ -142,8 +142,9 @@ def attention_parity(seed: int, *, interpret: bool = False, B=8, KH=2, G=6,
     rng = np.random.default_rng(seed)
     n_blocks = B * pages_per_seq + 1          # block 0 is the trash page
     q = jnp.asarray(rng.normal(size=(B, KH * G, D)), jnp.bfloat16)
-    kp = jnp.asarray(rng.normal(size=(n_blocks, KH, bs, D)), jnp.bfloat16)
-    vp = jnp.asarray(rng.normal(size=(n_blocks, KH, bs, D)), jnp.bfloat16)
+    # the stacked token-major pool of two layers; the kernel reads layer 1
+    kp = jnp.asarray(rng.normal(size=(2, n_blocks, bs, KH * D)), jnp.bfloat16)
+    vp = jnp.asarray(rng.normal(size=(2, n_blocks, bs, KH * D)), jnp.bfloat16)
     tables = np.zeros((B, pages_per_seq), np.int32)
     perm = rng.permutation(np.arange(1, n_blocks))
     lengths = rng.integers(0, pages_per_seq * bs, size=B).astype(np.int32)
@@ -152,11 +153,11 @@ def attention_parity(seed: int, *, interpret: bool = False, B=8, KH=2, G=6,
         used = lengths[b] // bs + 1
         tables[b, :used] = perm[b * pages_per_seq:b * pages_per_seq + used]
     tables, lengths = jnp.asarray(tables), jnp.asarray(lengths)
-    got = paged_attention_kernel(q, kp, vp, tables, lengths,
+    got = paged_attention_kernel(q, kp, vp, tables, lengths, 1,
                                  interpret=interpret)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(paged_attention_xla)(q.astype(jnp.float32), kp, vp,
-                                            tables, lengths)
+                                            tables, lengths, 1)
     err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
     check(bool(jnp.isfinite(got).all()), "paged_attention: non-finite output")
     check(err <= ATTN_ATOL, f"paged_attention kernel vs XLA oracle: max abs "

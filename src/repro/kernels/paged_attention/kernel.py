@@ -19,12 +19,17 @@ valid (the just-written token's K/V included), matching
 page 0 (the pool's trash block); their scores are masked to -inf before the
 softmax so they contribute exactly 0.
 
-Pages are laid out ``(N, KV_heads, block_size, D)``: each grid step reads
-one ``(block_size, D)`` tile, so the block's minor two dims are what the
-TPU compiler tiles.  Compiled for the chip, ``block_size`` must be a
-multiple of 8 and D a multiple of 128; interpret mode
-(the CPU validation path) relaxes all tiling.  VMEM working set per step:
-one K tile + one V tile + the (G, D) accumulator, a few KiB.
+Pages are laid out token-major, ``(L, N, block_size, KV_heads * D)``, one
+stack for every layer: a token's K (or V) row of all its KV heads is one
+contiguous row, so the decode step writes a new token's row in place into
+the pool it carries through the layer scan.
+The kernel reads head ``h``'s ``(block_size, D)`` tile as column block ``h``
+of the row-major page, and takes the layer as a third scalar-prefetch
+operand, so it reads straight from the stacked pool: no per-layer slice or
+relayout copy of the pool is ever made.  Compiled for the chip,
+``block_size`` must be a multiple of 8 and D a multiple of 128; interpret
+mode (the CPU validation path) relaxes all tiling.  VMEM working set per
+step: one K tile + one V tile + the (G, D) accumulator, a few KiB.
 """
 
 from __future__ import annotations
@@ -39,7 +44,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *refs,
+def _kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *refs,
             block_size: int, n_pages: int, return_state: bool):
     if return_state:
         o_ref, mo_ref, lo_ref, m_ref, l_ref, acc_ref = refs
@@ -93,12 +98,14 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *refs,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "return_state"))
-def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
-                           interpret: bool = False,
+def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths,
+                           layer=0, *, interpret: bool = False,
                            return_state: bool = False):
-    """q: (B, H, D); k_pages/v_pages: (N, KH, block_size, D);
-    block_tables: (B, n_pages) int32 physical page ids; lengths: (B,) int32
-    last valid position (inclusive).  Returns (B, H, D) in q.dtype.
+    """q: (B, H, D); k_pages/v_pages: (L, N, block_size, KH * D), the
+    stacked pool of every layer; block_tables: (B, n_pages) int32 physical
+    page ids; lengths: (B,) int32 last valid position (inclusive); layer:
+    int32 scalar, the layer whose pages are read.  Returns (B, H, D) in
+    q.dtype.
 
     With ``return_state=True`` the normalization epilogue is skipped and the
     call returns the online-softmax partial state ``(acc, m, l)`` — acc
@@ -106,7 +113,8 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
     shard split-KV combine.  A caller whose table covers only masked
     positions gets m = -inf, l = 0, acc = 0 (a neutral element)."""
     B, H, D = q.shape
-    N, KH, bs, _ = k_pages.shape
+    _, _, bs, F = k_pages.shape
+    KH = F // D
     G = H // KH
     n_pages = block_tables.shape[1]
     scale = D ** -0.5
@@ -114,14 +122,17 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
 
     kern = functools.partial(_kernel, block_size=bs, n_pages=n_pages,
                              return_state=return_state)
-    out_block = pl.BlockSpec((1, 1, G, D), lambda b, h, p, bt, ln: (b, h, 0, 0))
-    # one (block_size, D) tile of one KV head of one physical page: the
-    # page and head dims are squeezed, so the block's minor two dims are
+    out_block = pl.BlockSpec((1, 1, G, D),
+                             lambda b, h, p, bt, ln, ly: (b, h, 0, 0))
+    # one (block_size, D) tile of one KV head of one physical page of one
+    # layer: the layer and page dims are squeezed and the head is column
+    # block h of the token rows, so the block's minor two dims are
     # (block_size, D) -- tile-aligned for the chip's (8, 128) layout
     kv_block = pl.BlockSpec((None, None, bs, D),
-                            lambda b, h, p, bt, ln: (bt[b, p], h, 0, 0))
+                            lambda b, h, p, bt, ln, ly: (ly[0], bt[b, p],
+                                                         0, h))
     state_block = pl.BlockSpec((1, 1, G, 1),
-                               lambda b, h, p, bt, ln: (b, h, 0, 0))
+                               lambda b, h, p, bt, ln, ly: (b, h, 0, 0))
     if return_state:
         out_shape = (jax.ShapeDtypeStruct((B, KH, G, D), jnp.float32),
                      jax.ShapeDtypeStruct((B, KH, G, 1), jnp.float32),
@@ -131,10 +142,11 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
         out_shape = jax.ShapeDtypeStruct((B, KH, G, D), q.dtype)
         out_specs = out_block
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                   # block_tables, lengths
+        num_scalar_prefetch=3,           # block_tables, lengths, layer
         grid=(B, KH, n_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, p, bt, ln: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, G, D),
+                         lambda b, h, p, bt, ln, ly: (b, h, 0, 0)),
             kv_block,
             kv_block,
         ],
@@ -154,7 +166,7 @@ def paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths, *,
         interpret=interpret,
         name="paged_attention_decode",
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
-      qr, k_pages, v_pages)
+      jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), qr, k_pages, v_pages)
     if return_state:
         acc, m, l = out
         return acc, m, l

@@ -37,11 +37,12 @@ from repro.kernels.paged_attention.kernel import paged_attention_kernel
 from repro.kernels.paged_attention.ref import paged_attention_xla
 
 
-def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
+def paged_attention(q, k_pages, v_pages, block_tables, lengths, layer=0, *,
                     k_scale_pages=None, v_scale_pages=None,
                     backend: str = None):
-    """Paged decode attention; see :func:`.ref.paged_attention_xla` for the
-    argument contract.  ``backend`` overrides the process/trace default."""
+    """Paged decode attention over layer ``layer`` of the stacked pool; see
+    :func:`.ref.paged_attention_xla` for the argument contract.
+    ``backend`` overrides the process/trace default."""
     b = resolve_matmul_backend(backend)
     if b != "xla" and (k_scale_pages is not None or v_scale_pages is not None):
         note_backend_fallback(
@@ -50,20 +51,20 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         b = "xla"
     if b == "xla":
         return paged_attention_xla(
-            q, k_pages, v_pages, block_tables, lengths,
+            q, k_pages, v_pages, block_tables, lengths, layer,
             k_scale_pages=k_scale_pages, v_scale_pages=v_scale_pages)
     interpret = b == "kernel_interpret"
     mesh = shd.current_mesh()
     if mesh is not None:
         return _paged_attention_sharded(
-            q, k_pages, v_pages, block_tables, lengths,
+            q, k_pages, v_pages, block_tables, lengths, layer,
             interpret=interpret, mesh=mesh)
     return paged_attention_kernel(q, k_pages, v_pages, block_tables, lengths,
-                                  interpret=interpret)
+                                  layer, interpret=interpret)
 
 
-def _paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths, *,
-                             interpret: bool, mesh):
+def _paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths,
+                             layer, *, interpret: bool, mesh):
     """shard_map-partitioned paged-attention kernel over an active mesh.
 
     KV split: block-table page dim over "model" when divisible — lengths
@@ -71,7 +72,8 @@ def _paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths, *,
     so the kernel's inclusive ``pos <= length`` mask stays globally
     correct (far shards see a negative length = everything masked, which
     yields the neutral (m=-inf, l=0, acc=0) state).  Batch over "data"
-    when divisible.  Page pools ride in replicated.
+    when divisible.  Page pools (every layer's) and the layer index ride
+    in replicated.
     """
     axes = shd.mesh_axes_dict(mesh)
     model = axes.get("model", 1)
@@ -85,17 +87,18 @@ def _paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths, *,
 
     bt = jnp.asarray(block_tables, jnp.int32)
     ln = jnp.asarray(lengths, jnp.int32)
+    ly = jnp.asarray(layer, jnp.int32)
 
-    def run(q_l, kp, vp, bt_l, ln_l):
+    def run(q_l, kp, vp, bt_l, ln_l, ly_l):
         if kv_split:
             shard = jax.lax.axis_index("model")
             ln_shard = ln_l - shard * (pages_local * bs)
             acc, m, l = paged_attention_kernel(
-                q_l, kp, vp, bt_l, ln_shard, interpret=interpret,
+                q_l, kp, vp, bt_l, ln_shard, ly_l, interpret=interpret,
                 return_state=True)
             out = shd.combine_softmax_state(acc, m, l, "model")
             return out.reshape(q_l.shape).astype(q_l.dtype)
-        return paged_attention_kernel(q_l, kp, vp, bt_l, ln_l,
+        return paged_attention_kernel(q_l, kp, vp, bt_l, ln_l, ly_l,
                                       interpret=interpret)
 
     fn = shd.shard_map(
@@ -104,6 +107,7 @@ def _paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths, *,
                   P(None, None, None, None),
                   P(None, None, None, None),
                   P(batch_axis, "model" if kv_split else None),
-                  P(batch_axis)),
+                  P(batch_axis),
+                  P()),
         out_specs=P(batch_axis, None, None))
-    return fn(q, k_pages, v_pages, bt, ln)
+    return fn(q, k_pages, v_pages, bt, ln, ly)

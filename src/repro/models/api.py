@@ -269,18 +269,21 @@ def shard_cache(cfg: ArchConfig, cache, *, paged: bool = False):
 # ---------------------------------------------------------------------------
 
 def paged_cache_specs(cfg: ArchConfig, num_blocks: int, block_size: int):
-    """ShapeDtypeStruct pytree of the block-paged decode cache: every KV
-    leaf becomes (L, num_blocks, KV_heads, block_size[, head_dim]) — heads
-    outside the block, so one head's page is a contiguous (block_size,
-    head_dim) tile, the unit the paged-attention kernel reads.
-    Position-indexed KV families only — recurrent state has no sequence
-    axis to page."""
+    """ShapeDtypeStruct pytree of the block-paged decode cache, laid out
+    token-major: every KV leaf is (L, num_blocks, block_size,
+    KV_heads * head_dim) and every int8 scale leaf (L, num_blocks,
+    block_size, KV_heads).  One token's row of all its KV heads is
+    contiguous, so the decode step writes it in place into the pool it
+    carries through the layer scan; the paged-attention kernel reads one
+    head's (block_size, head_dim) tile as a column block of a page, at the
+    layer it is handed, straight from the stacked pool.  Position-indexed
+    KV families only — recurrent state has no sequence axis to page."""
     if cfg.family not in ("dense", "moe", "vlm"):
         raise ValueError(f"no paged cache layout for family {cfg.family!r}")
     hd = cfg.resolved_head_dim
-    kv = (cfg.num_layers, num_blocks, cfg.num_kv_heads, block_size, hd)
+    kv = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads * hd)
     if cfg.kv_cache_int8:
-        sc = (cfg.num_layers, num_blocks, cfg.num_kv_heads, block_size)
+        sc = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads)
         return {"k": _sds(kv, jnp.int8), "k_scale": _sds(sc, jnp.float32),
                 "v": _sds(kv, jnp.int8), "v_scale": _sds(sc, jnp.float32)}
     return {"k": _sds(kv, DTYPE), "v": _sds(kv, DTYPE)}
@@ -303,14 +306,12 @@ def paged_insert(cfg: ArchConfig, pages, src_cache, block_ids, src_index=0):
     block_ids = jnp.asarray(block_ids, jnp.int32)
 
     def put(page, src):
-        # src (L, B, T, KH, ...) -> row (L, T, KH, ...)
-        # -> (L, P, bs, KH, ...) -> page layout (L, P, KH, bs, ...)
+        # src (L, B, T, KH[, D]) -> row (L, T, KH[, D])
+        # -> token-major blocks (L, P, bs, KH[*D])
         row = jax.lax.dynamic_index_in_dim(src, src_index, axis=1,
                                            keepdims=False)
-        L, T = row.shape[0], row.shape[1]
-        P = block_ids.shape[0]
-        blocked = row.reshape(L, P, T // P, *row.shape[2:])
-        blocked = jnp.swapaxes(blocked, 2, 3)
+        blocked = row.reshape(page.shape[0], block_ids.shape[0],
+                              *page.shape[2:])
         return page.at[:, block_ids].set(blocked.astype(page.dtype))
 
     return jax.tree.map(put, pages, src_cache)

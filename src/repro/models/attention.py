@@ -168,45 +168,55 @@ def write_kv(cache, new, cache_len):
         new.astype(cache.dtype), mode="drop")
 
 
-def paged_write_kv(pages, new, block_ids, offsets):
-    """Write ``new`` (B, S, KH, ...) into block-paged ``pages``
-    (N, KH, bs, ...) at per-(sequence, row) (physical block, in-block
-    offset) positions, both (B, S).  Inactive rows and speculative overhang
-    past a slot's block table target the trash block (id 0) — written,
-    never read."""
-    return pages.at[block_ids, :, offsets].set(new.astype(pages.dtype))
+def paged_write_kv(pages, new, page_ids, offsets):
+    """Write ``new`` (B, S, KH[, D]) into the stacked token-major pages
+    (L, N, bs, KH[*D]) at per-(sequence, row) (page, in-block offset)
+    positions, both (B, S).  ``page_ids`` number the stack's pages
+    layer-major: page ``layer * N + block`` is physical block ``block`` of
+    layer ``layer``.  Each row is one contiguous token row of the pool
+    itself, so the pool carried through the layer scan is updated in place
+    (``tests/test_tpu_compile.py`` pins that no pool-sized temporary or
+    copy appears).  Inactive rows and speculative overhang past a slot's
+    block table target the layer's trash block (block 0) — written, never
+    read."""
+    N = pages.shape[1]
+    ids = page_ids.reshape(-1)
+    rows = new.reshape(ids.shape[0], pages.shape[-1]).astype(pages.dtype)
+    return pages.at[ids // N, ids % N, offsets.reshape(-1)].set(rows)
 
 
-def paged_verify_attention(q, k_pages, v_pages, block_tables, cache_len, *,
-                           k_scale=None, v_scale=None):
+def paged_verify_attention(q, k_pages, v_pages, block_tables, cache_len,
+                           layer, *, k_scale=None, v_scale=None):
     """Multi-token verify attention over block-paged KV: gather each slot's
-    pages dense through its block table, then run the same causal-wedge
-    masking as :func:`decode_attention`.  The Pallas decode kernel is a
-    one-query-row program, so the S > 1 verify path always takes the XLA
-    gather formulation (it partitions under GSPMD on a mesh, like the
-    paged-attention oracle)."""
-    from repro.kernels.paged_attention.ref import gather_pages
+    pages of layer ``layer`` dense through its block table, then run the
+    same causal-wedge masking as :func:`decode_attention`.  The Pallas
+    decode kernel is a one-query-row program, so the S > 1 verify path
+    always takes the XLA gather formulation (it partitions under GSPMD on a
+    mesh, like the paged-attention oracle)."""
+    from repro.kernels.paged_attention.ref import gather_kv, gather_pages
+    KH = k_pages.shape[-1] // q.shape[3]
 
     def lin(p):
-        return gather_pages(p, block_tables)
+        return gather_pages(p, block_tables, layer)
 
     return decode_attention(
-        q, lin(k_pages), lin(v_pages), cache_len,
+        q, gather_kv(k_pages, block_tables, layer, KH),
+        gather_kv(v_pages, block_tables, layer, KH), cache_len,
         k_scale=lin(k_scale) if k_scale is not None else None,
         v_scale=lin(v_scale) if v_scale is not None else None)
 
 
-def paged_decode_attention(q, k_pages, v_pages, block_tables, cache_len, *,
-                           k_scale=None, v_scale=None):
-    """q (B,1,H,D) against block-paged K/V (N, KH, bs, D) through per-slot
-    block tables (B, P); positions <= cache_len valid, exactly as
-    :func:`decode_attention`.  Dispatches to the Pallas paged-attention
-    kernel / XLA gather oracle per the active matmul backend (under a mesh
-    the kernel runs inside ``shard_map`` — ``kernels/paged_attention/ops.py``)."""
+def paged_decode_attention(q, k_pages, v_pages, block_tables, cache_len,
+                           layer, *, k_scale=None, v_scale=None):
+    """q (B,1,H,D) against layer ``layer`` of the stacked token-major pages
+    (L, N, bs, KH * D) through per-slot block tables (B, P); positions
+    <= cache_len valid, exactly as :func:`decode_attention`.  Dispatches to
+    the Pallas paged-attention kernel / XLA gather oracle per the active
+    matmul backend (under a mesh the kernel runs inside ``shard_map`` —
+    ``kernels/paged_attention/ops.py``)."""
     from repro.kernels.paged_attention.ops import paged_attention
-    B, _, H, D = q.shape
     out = paged_attention(q[:, 0], k_pages, v_pages, block_tables,
-                          jnp.asarray(cache_len),
+                          jnp.asarray(cache_len), layer,
                           k_scale_pages=k_scale, v_scale_pages=v_scale)
     return out[:, None]
 

@@ -200,9 +200,15 @@ def prefill(params, cfg, batch, cache_T: int, prompt_lens=None):
 
 def _decode_common(params, cfg, batch, *, write_fn, attend_fn):
     """Shared decode/verify body over S >= 1 appended tokens; the cache
-    layout enters only through ``write_fn(cache_leaf, new)`` (install the
-    new tokens' K/V/scales) and ``attend_fn(q, kc, vc, ksc, vsc)``
-    (attention over that layout).  Returns (logits (B, S, V), cache)."""
+    layout enters only through ``write_fn(cache_leaf, layer, new)`` (install
+    the new tokens' K/V/scales) and ``attend_fn(q, cache, layer)``
+    (attention over that layout).  Returns (logits (B, S, V), cache).
+
+    The slab cache is scanned per layer (``xs``) and restacked (``ys``).
+    The block-paged pool (the batch carries ``block_tables``) rides in the
+    scan's carry whole, indexed by the layer: new rows are written into it
+    in place and the kernel reads its layer where it lies, so the step
+    makes no per-layer slice or stacked copy of the pool."""
     mode = cfg.matmul_mode
     probing = _probe.tap_active()
     tokens, cache = batch["tokens"], batch["cache"]
@@ -217,25 +223,20 @@ def _decode_common(params, cfg, batch, *, write_fn, attend_fn):
     cos, sin = _angles(cfg, pos)
     hd = cfg.resolved_head_dim
 
-    int8kv = cfg.kv_cache_int8
-
-    def body(x, layer_in):
-        if int8kv:
-            lp, kc, ksc, vc, vsc = layer_in
-        else:
-            lp, kc, vc = layer_in
+    def layer_step(x, lp, kv, li):
+        """One layer: ``kv`` holds this layer's slab leaves, or the whole
+        paged pool with ``li`` naming the layer."""
         h = layers.rms_norm(lp["attn_norm"], x, cfg.norm_eps)
         q, k, v = attention.qkv_proj(lp["attn"], h, cfg, mode)
         q = layers.apply_rope(q, cos, sin)
         k = layers.apply_rope(k, cos, sin)
-        if int8kv:
+        if cfg.kv_cache_int8:
             k, ks_, v, vs_ = attention.quantize_kv(k, v)
-            ksc = write_fn(ksc, ks_)
-            vsc = write_fn(vsc, vs_)
-        kc = write_fn(kc, k)
-        vc = write_fn(vc, v)
-        out = attend_fn(q, kc, vc,
-                        ksc if int8kv else None, vsc if int8kv else None)
+            new = {"k": k, "k_scale": ks_, "v": v, "v_scale": vs_}
+        else:
+            new = {"k": k, "v": v}
+        kv = {name: write_fn(kv[name], li, new[name]) for name in kv}
+        out = attend_fn(q, kv, li)
         out = out.reshape(B, S, cfg.num_heads * hd)
         x = x + layers.dense(lp["attn"]["wo"], out, mode)
         h = layers.rms_norm(lp["ffn_norm"], x, cfg.norm_eps)
@@ -243,23 +244,29 @@ def _decode_common(params, cfg, batch, *, write_fn, attend_fn):
             f, _ = moe_ffn(lp["moe"], h, cfg, mode)
         else:
             f = layers.ffn(lp["ffn"], h, cfg.ffn_type, mode)
-        x = x + f
-        ys = (kc, ksc, vc, vsc) if int8kv else (kc, vc)
-        if probing:
-            ys = ys + (_probe.drain_layer(),)
-        return x, ys
+        return x + f, kv
 
-    if int8kv:
-        xs = (params["layers"], cache["k"], cache["k_scale"],
-              cache["v"], cache["v_scale"])
-        x, ys = jax.lax.scan(body, x, xs)
-        ks, kss, vs, vss = ys[:4]
-        new_cache = {"k": ks, "k_scale": kss, "v": vs, "v_scale": vss}
+    def taps():
+        return (_probe.drain_layer(),) if probing else ()
+
+    if "block_tables" in batch:
+        def body(carry, layer_in):
+            x, pool = carry
+            lp, li = layer_in
+            x, pool = layer_step(x, lp, pool, li)
+            return (x, pool), taps()
+
+        (x, new_cache), ys = jax.lax.scan(
+            body, (x, cache),
+            (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     else:
-        x, ys = jax.lax.scan(body, x, (params["layers"],
-                                       cache["k"], cache["v"]))
-        ks, vs = ys[:2]
-        new_cache = {"k": ks, "v": vs}
+        def body(x, layer_in):
+            lp, kv = layer_in
+            x, kv = layer_step(x, lp, kv, None)
+            return x, (kv,) + taps()
+
+        x, ys = jax.lax.scan(body, x, (params["layers"], cache))
+        new_cache, ys = ys[0], ys[1:]
     if probing:
         _probe.emit_layers(ys[-1])
     x = layers.rms_norm(params["final_norm"], x, cfg.norm_eps)
@@ -272,7 +279,7 @@ def _slab_fns(batch):
     ``batch`` (S = 1: decode; S > 1: speculative verify)."""
     cache_len = jnp.asarray(batch["cache_len"])
 
-    def write_fn(c, new):
+    def write_fn(c, _layer, new):
         c = attention.write_kv(c, new, cache_len)
         if c.ndim == 4:   # KV leaves (B, T, KH, hd)
             c = shard(c, "batch", "cache_seq", "heads", None)
@@ -283,9 +290,10 @@ def _slab_fns(batch):
         # reshard copy instead of aliasing)
         return c
 
-    def attend_fn(q, kc, vc, ksc, vsc):
-        return attention.decode_attention(q, kc, vc, cache_len,
-                                          k_scale=ksc, v_scale=vsc)
+    def attend_fn(q, kv, _layer):
+        return attention.decode_attention(
+            q, kv["k"], kv["v"], cache_len,
+            k_scale=kv.get("k_scale"), v_scale=kv.get("v_scale"))
 
     return write_fn, attend_fn
 
@@ -297,7 +305,7 @@ def _paged_fns(batch):
     kernel / XLA oracle, S > 1 takes the dense-gather verify formulation."""
     cache_len = jnp.asarray(batch["cache_len"])
     tables = jnp.asarray(batch["block_tables"], jnp.int32)
-    bs = batch["cache"]["k"].shape[3]          # (L, N, KH, bs, D)
+    bs = batch["cache"]["k"].shape[2]          # (L, N, bs, KH * D)
     S = batch["tokens"].shape[1]
     P = tables.shape[1]
     # physical write target per (slot, row): table entry at pos // bs
@@ -307,15 +315,16 @@ def _paged_fns(batch):
     blk = jnp.where(bi < P, blk, 0)      # overhang -> trash block
     off = pos % bs
 
-    def write_fn(c, new):
-        return attention.paged_write_kv(c, new, blk, off)
+    def write_fn(pool, layer, new):
+        return attention.paged_write_kv(pool, new,
+                                        layer * pool.shape[1] + blk, off)
 
-    def attend_fn(q, kc, vc, ksc, vsc):
-        if S == 1:
-            return attention.paged_decode_attention(
-                q, kc, vc, tables, cache_len, k_scale=ksc, v_scale=vsc)
-        return attention.paged_verify_attention(
-            q, kc, vc, tables, cache_len, k_scale=ksc, v_scale=vsc)
+    def attend_fn(q, pool, layer):
+        attend = (attention.paged_decode_attention if S == 1
+                  else attention.paged_verify_attention)
+        return attend(q, pool["k"], pool["v"], tables, cache_len, layer,
+                      k_scale=pool.get("k_scale"),
+                      v_scale=pool.get("v_scale"))
 
     return write_fn, attend_fn
 
@@ -349,13 +358,14 @@ def verify_step(params, cfg, batch):
 def decode_step_paged(params, cfg, batch):
     """One-token decode against a block-paged KV cache.
 
-    batch: tokens (B,1); cache {k,v[,k_scale,v_scale]} with KV paged as
-    (L, num_blocks, KH, block_size, Dh); block_tables (B, P) int32 physical
-    page ids; cache_len (B,) int32 per-slot positions.  The new token's K/V
-    is scattered to (table[pos // bs], pos % bs) per slot, and attention
-    gathers through the block table (Pallas kernel / XLA oracle per the
-    active backend).  The page pool has no batch/cache_seq axes to lay on
-    the mesh, so paged leaves stay replicated.  Returns (logits, cache)."""
+    batch: tokens (B,1); cache {k,v[,k_scale,v_scale]} with KV paged
+    token-major as (L, num_blocks, block_size, KH * Dh); block_tables (B, P)
+    int32 physical page ids; cache_len (B,) int32 per-slot positions.  The
+    new token's K/V row is written in place at (layer, table[pos // bs],
+    pos % bs) per slot, and attention reads the layer's pages through the
+    block table (Pallas kernel / XLA oracle per the active backend).  The
+    page pool has no batch/cache_seq axes to lay on the mesh, so paged
+    leaves stay replicated.  Returns (logits, cache)."""
     write_fn, attend_fn = _paged_fns(batch)
     logits, cache = _decode_common(params, cfg, batch,
                                    write_fn=write_fn, attend_fn=attend_fn)

@@ -20,9 +20,10 @@ cheap control logic:
     into a shared block, and the block-budget accounting the scheduler uses
     for admission.
 
-Physical layout: every KV leaf is paged as ``(L, num_blocks, KV_heads,
-block_size[, head_dim])``; a request's logical positions ``[0, len)`` live
-at ``pages[:, table[i], :, pos % block_size]`` with ``i = pos // block_size``.
+Physical layout: every KV leaf is paged token-major as ``(L, num_blocks,
+block_size, KV_heads * head_dim)`` (int8 scales ``(L, num_blocks,
+block_size, KV_heads)``); a request's logical positions ``[0, len)`` live
+at ``pages[:, table[i], pos % block_size]`` with ``i = pos // block_size``.
 Block id 0 is reserved as a trash/scratch block: unused table entries point
 at it, so every gather/scatter stays in-range at fixed shapes (writes that
 must go nowhere land there, reads of it are masked by ``cache_len``).

@@ -11,10 +11,14 @@ device-shaped lives here, behind one ``Executor`` interface:
     oracle exactly as before the engine/executor split.
   * **buffer donation** — the pooled decode cache is donated
     (``donate_argnums``) into the decode step, the decode scan chunk, and
-    the ``slot_insert``/``paged_insert``/``copy_block`` surgery ops: per-step
-    KV updates and admissions alias the cache buffer in place instead of
-    allocating a second cache-sized copy (``tests/test_executor.py`` pins
-    this with an HLO aliasing regression test).
+    the ``slot_insert``/``paged_insert``/``copy_block`` surgery ops, so
+    each returns its cache in the input's buffer rather than in a second
+    cache-sized output (``tests/test_executor.py`` pins the aliasing in the
+    HLO).  Donation alone does not keep a step from copying the cache
+    inside: the block-paged decode step also carries its pool through the
+    layer scan and writes each new K/V row in place, which is what keeps a
+    pool-sized temporary and the per-layer copies out of the program
+    (``tests/test_tpu_compile.py`` compiles it for the chip to pin that).
   * **device placement** — params are placed once at construction; caches
     are allocated through the executor so their residency/sharding is an
     executor decision, not an engine one.
@@ -283,8 +287,9 @@ class Executor:
         """``fn(cache, step, keys, counts) -> (tokens, new_cache)`` for the
         continuous path: decode + per-slot sampling fused into ONE dispatch
         (only the (n_slots,) sampled tokens cross to the host, never the
-        logits), with the cache buffer DONATED — the per-step KV update
-        aliases the pool instead of copying it.  ``paged`` routes through
+        logits), with the cache buffer DONATED — the returned cache takes
+        the input's buffer (the paged step also updates it in place; see
+        the module docstring).  ``paged`` routes through
         the block-table decode step (``step`` then carries
         ``block_tables``).  ``probed=True`` jits a separate tapped variant
         returning ``(tokens, new_cache, stats)`` (donation unchanged)."""

@@ -1,7 +1,7 @@
 """Execution layer: the engine/executor split, the cache-donation contract
-(per-step KV updates and admissions must alias the pooled cache buffer, not
-copy it), and the logical-axis -> PartitionSpec helpers the mesh executor
-places params/caches with."""
+(per-step KV updates and admissions return the cache in the donated pool
+buffer, not in a second cache-sized output), and the logical-axis ->
+PartitionSpec helpers the mesh executor places params/caches with."""
 
 import inspect
 
@@ -92,9 +92,11 @@ class TestCacheDonation:
 
     def test_decode_step_aliases_cache_in_hlo(self):
         """Regression: the jitted decode step's HLO must alias every cache
-        leaf input to an output (tf.aliasing_output), i.e. the per-step KV
-        update runs in the donated buffer instead of materializing a second
-        cache-sized array."""
+        leaf input to an output (tf.aliasing_output), i.e. the returned
+        cache takes the donated buffer instead of a second cache-sized
+        output.  Aliasing says nothing of the copies a step makes inside:
+        the paged step's in-place pool update is pinned by the compile
+        test for the chip (``tests/test_tpu_compile.py``)."""
         engine = _engine(_dense_cfg(), max_new_tokens=4)
         # cache big enough that activations/temps cannot mask a stray copy
         cache, step, keys, counts = self._step_args(engine, n_slots=4,

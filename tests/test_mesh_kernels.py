@@ -68,9 +68,9 @@ def test_backend_fallback_ledger_counts_and_paged_scale_demotion():
         B, H, KH, D, bs, P = 2, 2, 1, 8, 4, 2
         N = 5
         q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(N, KH, bs, D)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(N, KH, bs, D)), jnp.float32)
-        ks = jnp.ones((N, KH, bs), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(1, N, bs, KH * D)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(1, N, bs, KH * D)), jnp.float32)
+        ks = jnp.ones((1, N, bs, KH), jnp.float32)
         bt = jnp.asarray(rng.integers(1, N, size=(B, P)), jnp.int32)
         ln = jnp.asarray(rng.integers(0, P * bs, size=(B,)), jnp.int32)
 
@@ -173,32 +173,35 @@ def test_mesh_paged_attention_kernel_parity():
     """Paged-attention kernel under the 2x4 mesh vs the XLA gather oracle:
     the split-KV path (page dim divisible by "model" -> per-shard online
     softmax + (m, l, acc) cross-shard combine) and the replicated path
-    (ragged page count), ragged lengths including length 0."""
+    (ragged page count), ragged lengths including length 0, each reading
+    one layer of a stacked three-layer pool through the layer operand."""
     _run(_HEADER + """
     from repro.kernels.paged_attention.ops import paged_attention
     from repro.kernels.paged_attention.ref import paged_attention_xla
 
     rng = np.random.default_rng(0)
-    #          B  H  KH  D  bs  P     (P % 4 == 0 -> KV split over "model")
-    cases = [(4, 4, 2, 16, 4, 8),
-             (4, 8, 4, 16, 2, 12),
-             (4, 4, 2, 16, 4, 5),     # ragged page count: replicated
-             (6, 2, 2,  8, 4, 4)]     # B % 2 == 0 but B % 4 != 0
-    for (B, H, KH, D, bs, P) in cases:
+    L = 3
+    #          B  H  KH  D  bs  P  layer (P % 4 == 0 -> KV split)
+    cases = [(4, 4, 2, 16, 4, 8, 2),
+             (4, 8, 4, 16, 2, 12, 1),
+             (4, 4, 2, 16, 4, 5, 1),    # ragged page count: replicated
+             (6, 2, 2,  8, 4, 4, 0)]    # B % 2 == 0 but B % 4 != 0
+    for (B, H, KH, D, bs, P, layer) in cases:
         N = P * B + 1
         q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(N, KH, bs, D)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(N, KH, bs, D)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(L, N, bs, KH * D)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(L, N, bs, KH * D)), jnp.float32)
         bt = jnp.asarray(rng.integers(1, N, size=(B, P)), jnp.int32)
         ln = np.asarray(rng.integers(0, P * bs, size=(B,)), np.int32)
         ln[0] = 0                      # only the just-written token valid
         ln = jnp.asarray(ln)
-        ref = paged_attention_xla(q, kp, vp, bt, ln)
+        ref = paged_attention_xla(q, kp, vp, bt, ln, layer)
         with jax.set_mesh(mesh), \\
              bpm.use_matmul_backend("kernel_interpret"):
-            got = jax.jit(paged_attention)(q, kp, vp, bt, ln)
+            got = jax.jit(paged_attention)(q, kp, vp, bt, ln,
+                                           jnp.int32(layer))
         np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)
-        print("OK", (B, H, KH, D, bs, P))
+        print("OK", (B, H, KH, D, bs, P, layer))
 """)
 
 

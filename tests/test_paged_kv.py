@@ -192,6 +192,69 @@ class TestPagedMemoryBehavior:
 
 
 # ---------------------------------------------------------------------------
+# Token-major page layout: insert, gather and copy round trips
+# ---------------------------------------------------------------------------
+
+class TestTokenMajorLayout:
+    @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+    def test_paged_insert_round_trips_through_gather(self, int8):
+        """A prefill row lands token-major in the pages its block ids name
+        (shared blocks redirected to trash stay untouched), and gathering
+        each layer back through the table returns the row it came from."""
+        from repro.kernels.paged_attention.ref import gather_kv, gather_pages
+        cfg = _dense_cfg(kv_cache_int8=int8)
+        L, KH, D, bs, N = (cfg.num_layers, cfg.num_kv_heads,
+                           cfg.resolved_head_dim, 4, 9)
+        T = 3 * bs
+        keys = jax.random.split(jax.random.PRNGKey(7), 2)
+        src = {"k": jax.random.normal(keys[0], (L, 2, T, KH, D)),
+               "v": jax.random.normal(keys[1], (L, 2, T, KH, D))}
+        if int8:
+            src = {"k": (src["k"] * 40).astype(jnp.int8),
+                   "k_scale": jnp.abs(src["k"][..., 0]) + 0.5,
+                   "v": (src["v"] * 40).astype(jnp.int8),
+                   "v_scale": jnp.abs(src["v"][..., 0]) + 0.5}
+        pages = api.zeros_paged_cache(cfg, N, bs)
+        assert pages["k"].shape == (L, N, bs, KH * D)
+        if int8:
+            assert pages["k_scale"].shape == (L, N, bs, KH)
+        ids = jnp.asarray([5, 2, 7], jnp.int32)
+        pages = api.paged_insert(cfg, pages, src, ids, src_index=1)
+        table = ids[None]
+        for layer in range(L):
+            for name in ("k", "v"):
+                np.testing.assert_array_equal(
+                    np.asarray(gather_kv(pages[name], table, layer, KH))[0],
+                    np.asarray(src[name][layer, 1]).astype(
+                        pages[name].dtype))
+            if int8:
+                np.testing.assert_array_equal(
+                    np.asarray(gather_pages(pages["k_scale"], table,
+                                            layer))[0],
+                    np.asarray(src["k_scale"][layer, 1]))
+        untouched = [b for b in range(N) if b not in (5, 2, 7)]
+        for leaf in jax.tree.leaves(pages):
+            assert not np.asarray(leaf[:, untouched]).any()
+
+    def test_copy_block_copies_every_layer(self):
+        """Copy-on-write duplicates one physical page across every layer
+        and leaf, and nothing else."""
+        from repro.serving import make_executor
+        cfg = _dense_cfg()
+        ex = make_executor(cfg)
+        pages = ex.zeros_paged_cache(6, 4)
+        pages = jax.tree.map(
+            lambda p: jax.random.normal(jax.random.PRNGKey(3), p.shape,
+                                        jnp.float32).astype(p.dtype), pages)
+        before = jax.tree.map(np.asarray, pages)
+        after = jax.tree.map(np.asarray, ex.copy_block(pages, 4, 1))
+        for name in before:
+            want = before[name].copy()
+            want[:, 4] = before[name][:, 1]
+            np.testing.assert_array_equal(after[name], want)
+
+
+# ---------------------------------------------------------------------------
 # Power-of-two prefill bucketing (scheduler satellite)
 # ---------------------------------------------------------------------------
 

@@ -12,12 +12,14 @@ compiles (an entry written for a described chip cannot be read back).
 """
 
 import os
+import re
 
 import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import bp_matmul as bp_dispatch
 from repro.kernels.bitparticle_matmul.ops import bp_matmul
 from repro.kernels.paged_attention.kernel import paged_attention_kernel
 
@@ -74,16 +76,71 @@ def test_bp_matmul_compiles_under_raised_default_precision(one_chip, approx):
 @pytest.mark.parametrize("return_state", [False, True],
                          ids=["normalized", "split_kv_state"])
 def test_paged_attention_compiles_for_v5e(one_chip, return_state):
-    B, KH, G, D, bs, pages = 8, 2, 6, 128, 16, 32
+    L, B, KH, G, D, bs, pages = 3, 8, 2, 6, 128, 16, 32
     n_blocks = B * pages + 1
     text = _compiled_text(
         lambda *a: paged_attention_kernel(*a, return_state=return_state),
         one_chip, ((B, KH * G, D), jnp.bfloat16),
-        ((n_blocks, KH, bs, D), jnp.bfloat16),
-        ((n_blocks, KH, bs, D), jnp.bfloat16),
-        ((B, pages), jnp.int32), ((B,), jnp.int32))
+        ((L, n_blocks, bs, KH * D), jnp.bfloat16),
+        ((L, n_blocks, bs, KH * D), jnp.bfloat16),
+        ((B, pages), jnp.int32), ((B,), jnp.int32), ((), jnp.int32))
     assert "tpu_custom_call" in text
     assert "paged_attention_decode" in text
+
+
+def _pool_sized(text, n_blocks):
+    """The compiled instructions that copy or slice a pool-shaped array:
+    ``copy``/``dynamic-slice`` ops, or fusions named after them, whose
+    result has the pool's block count as a dimension."""
+    hits = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(\S+) = \w+\[([\d,]*)\]\S* (\S+?)\(",
+                     line)
+        if not m or n_blocks not in [int(d) for d in m[2].split(",") if d]:
+            continue
+        name, op = m[1], m[3]
+        if op in ("copy", "copy-start", "copy-done", "dynamic-slice") or (
+                op == "fusion" and re.search(r"copy|dynamic-slice", name)):
+            hits.append(line.strip()[:160])
+    return hits
+
+
+def test_paged_decode_step_keeps_the_pool_in_place(one_chip):
+    """The paged decode step at qwen2-1.5b widths (d 1536, GQA 12/2, head
+    dim 128, block 16; two layers, a 4000-block pool, 20 slots) holds no
+    pool-sized temporary and makes no pool-shaped copy or slice: new K/V
+    rows are written into the pool the layer scan carries and the kernel
+    reads its layer where it lies.  Scanning the pool per layer as scan
+    inputs and outputs held 263 MB of temporaries here, twice the 131 MB
+    pool, and 16 pool-shaped copies and slices."""
+    from repro.configs.base import get_arch
+    from repro.models import api
+    from repro.models.layers import quantize_dense_params
+    L, n_blocks, bs, B, P = 2, 4000, 16, 20, 161
+    cfg = get_arch("qwen2-1.5b").replace(num_layers=L, matmul_mode="bp_exact")
+    assert (cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
+            cfg.resolved_head_dim) == (1536, 12, 2, 128)
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(quantize_dense_params(jax.eval_shape(
+        lambda k: api.init(k, cfg), jax.random.PRNGKey(0))))
+    pool = on_chip(api.paged_cache_specs(cfg, n_blocks, bs))
+    step = on_chip({"tokens": jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                    "cache_len": jax.ShapeDtypeStruct((B,), jnp.int32),
+                    "block_tables": jax.ShapeDtypeStruct((B, P), jnp.int32)})
+
+    def decode_step(p, cache, step):
+        logits, cache = api.decode_step_paged(p, cfg, dict(step, cache=cache))
+        return jnp.argmax(logits, axis=-1), cache
+
+    with bp_dispatch.use_matmul_backend("kernel"):
+        compiled = jax.jit(decode_step, donate_argnums=(1,)).lower(
+            params, pool, step).compile()
+    text = compiled.as_text()
+    assert "paged_attention_decode" in text
+    assert _pool_sized(text, n_blocks) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
 def test_weight_bit_sparsity_reduction_fits_beside_the_pool(one_chip):
