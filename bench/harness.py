@@ -4,9 +4,12 @@ The order inside one process (``run.py`` calls :func:`main`):
 
 1. ``jax.devices()[0]`` must be a TPU with as many chips as the cell
    asks for, else the run exits non-zero before any model is built.
-2. Weights are made on the device from ``--seed`` (``weights.py``).
+2. Weights are made from ``--seed`` (``weights.py``) on the devices that
+   serve them: on a cell's ``serve.mesh_shape``, in the shardings the
+   program places them with.
 3. The real ``FrontDoor`` starts over one ``Replica``: the paged
-   ``ServeLoop``, ``matmul_backend="auto"``, kernels on.
+   ``ServeLoop``, ``matmul_backend="auto"``, kernels on, on the cell's
+   mesh where it names one.
 4. Every prefill shape the mix can reach (power-of-two bucket x group
    size) and the decode step are compiled, from the persistent cache
    after the first run; then one request crosses the HTTP path.
@@ -15,7 +18,8 @@ The order inside one process (``run.py`` calls :func:`main`):
    records a sub-window in the middle.
 6. After the window: peak memory is read, the program is stopped and
    freed, and a seeded sample of the finished requests is compared with
-   the float32 reference (``reference.py``).
+   the configuration's float32 reference (``reference.py``, or the
+   module its ``reference_file`` names).
 7. The last stdout line is the result object; the numbers compared, with
    their limits, are the last stderr lines and the result's last key.
 """
@@ -101,36 +105,101 @@ class CompileCounter:
 
 
 def arch_config(conf: dict, name: str):
-    """The program's ``ArchConfig`` for a configuration file."""
+    """The program's ``ArchConfig`` for a configuration file: the sizes
+    from its published keys, and every key of its ``serving`` block that
+    is an ``ArchConfig`` field.  ``name`` and ``matmul_backend`` are the
+    harness's, and a size is stated once, under its published key."""
+    import dataclasses
     from repro.configs.base import ArchConfig
-    sv = conf["serving"]
     H = conf["num_attention_heads"]
-    return ArchConfig(
-        name=name, family=sv["family"],
+    kw = dict(
         num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
         num_heads=H, num_kv_heads=conf["num_key_value_heads"],
         d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
         head_dim=conf.get("head_dim") or conf["hidden_size"] // H,
-        qkv_bias=sv["qkv_bias"], ffn_type=sv["ffn_type"],
         rope_theta=float(conf["rope_theta"]),
         tie_embeddings=conf["tie_word_embeddings"],
-        norm_eps=float(conf["rms_norm_eps"]),
-        matmul_mode=sv["matmul_mode"], matmul_backend="auto")
+        norm_eps=float(conf["rms_norm_eps"]))
+    fields = {f.name for f in dataclasses.fields(ArchConfig)}
+    for k, v in conf["serving"].items():
+        if k not in fields:
+            continue
+        if k in kw or k in ("name", "matmul_backend"):
+            raise ValueError(f"serving.{k} is not the configuration's to "
+                             f"set: the harness sets it")
+        kw[k] = tuple(v) if isinstance(v, list) else v
+    return ArchConfig(name=name, matmul_backend="auto", **kw)
 
 
-def check_layout(conf: dict, cfg) -> None:
-    """The benchmark's weight layout must be the tree the program serves."""
+def mesh_shape(sp: dict):
+    """The cell's ``serve.mesh_shape`` as a ``(data, model)`` tuple, None
+    where it names none; refused unless its devices are the cell's
+    ``chips``."""
+    shape = sp["cell"]["serve"].get("mesh_shape")
+    if shape is None:
+        return None
+    shape = tuple(int(n) for n in shape)
+    chips = int(sp["entry"]["chips"])
+    if len(shape) != 2 or min(shape) < 1 or math.prod(shape) != chips:
+        raise ValueError(f"cell {sp['entry']['name']!r}: mesh_shape "
+                         f"{list(shape)} is not (data, model) over its "
+                         f"{chips} chips")
+    return shape
+
+
+def cell_mesh(shape, devices):
+    """``(mesh, the cell's devices)``: the program's serving mesh for a
+    ``mesh_shape`` (None for none) and the devices it spans, else the
+    first device alone."""
+    if shape is None:
+        return None, list(devices[:1])
+    from repro.serving.executor import make_serving_mesh
+    mesh = make_serving_mesh(shape)
+    return mesh, list(mesh.devices.flat)
+
+
+def served_model(root: str, sp: dict, mesh) -> dict:
+    """What the weights are drawn from: the configuration's reference
+    module, the served layout (the module's ``layout`` where it has one)
+    and the shardings the program places that layout with on ``mesh``
+    (``MeshExecutor._place_params``; None on one device)."""
+    from bench import weights
+    ref = spec_mod.reference(root, sp["conf"])
+    shapes = getattr(ref, "layout", weights.layout)(sp["conf"])
+    shardings = None
+    if mesh is not None:
+        from repro.distributed.sharding import named_shardings
+        shardings = named_shardings(shapes, "decode", mesh)
+    return {"ref": ref, "shapes": shapes, "shardings": shardings}
+
+
+def draw_weights(sp: dict, model: dict, seed: int):
+    """The served parameters from ``seed``, drawn into the model's
+    shardings by ``weights.build``."""
+    from bench import weights
+    return weights.build(sp["conf"], seed, model["shardings"],
+                         shapes=model["shapes"],
+                         draw=getattr(model["ref"], "draw_leaf", None))
+
+
+def check_layout(have, cfg) -> None:
+    """The benchmark's weight layout ``have`` must be the tree the
+    program serves."""
     import jax
     from repro.models import api
     from repro.models.layers import quantize_dense_params
-    from bench import weights
     want = quantize_dense_params(jax.eval_shape(
         lambda k: api.init(k, cfg), jax.random.PRNGKey(0)))
-    have = weights.layout(conf)
     sig = lambda t: jax.tree.map(lambda s: (s.shape, str(s.dtype)), t)
     if sig(want) != sig(have):
-        raise ValueError("bench/weights.py layout differs from the "
+        raise ValueError("the benchmark's weight layout differs from the "
                          "program's served parameter tree")
+
+
+def peak_bytes(devices) -> int:
+    """Peak bytes in use on the fullest of ``devices``."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devices)
 
 
 def prefill_buckets(pmin: int, pmax: int, cache_T: int):
@@ -154,6 +223,7 @@ class Server:
         sc = ServeConfig(max_new_tokens=lim["output"], temperature=0.0,
                          eos_id=None, cache_backend="paged",
                          block_size=sv["block_size"],
+                         mesh_shape=mesh_shape(sp),
                          telemetry=(Telemetry(annotate_device=True)
                                     if trace else None))
         self.engine = ServingEngine(self.cfg, params, sc)
@@ -295,13 +365,14 @@ class Server:
         gc.collect()
 
 
-def check_outputs(sp: dict, schedule, records, seed: int, window_s: float,
-                  *, control_bits: int = 0):
+def check_outputs(sp: dict, model: dict, schedule, records, seed: int,
+                  window_s: float, *, control_bits: int = 0):
     """Compare a seeded sample of the window's finished requests, the
-    longest among them, with the float32 reference.  Returns the widest
-    gap (and the control's, with ``control_bits``)."""
+    longest among them, with the float32 reference, on weights drawn as
+    the served ones were.  Returns the widest gap (and the control's, with
+    ``control_bits``)."""
     import numpy as np
-    from bench import reference, weights
+    reference = model["ref"]
     conf, mix = sp["conf"], sp["mix"]
     done = [r for r in records if stats.in_window(r, window_s) and r["ok"]]
     if not done:
@@ -314,7 +385,7 @@ def check_outputs(sp: dict, schedule, records, seed: int, window_s: float,
                                                       replace=False)]
     lim = traffic.max_lengths(mix)
     S = reference.seq_len(lim["prompt"], lim["output"])
-    params = weights.build(conf, seed)
+    params = draw_weights(sp, model, seed)
     prompts = {r["id"]: r["prompt"] for r in schedule}
     widest, widest_ctrl = 0.0, 0.0
     for r in picks:
@@ -342,18 +413,20 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
     marks = [("start", t_start)]
     sp = spec_mod.cell(root, name)
     chips = int(sp["entry"]["chips"])
+    shape = mesh_shape(sp)
     enable_compile_cache(root)
     devices = require_accelerator(chips)
     import jax
     dev = devices[0]
     pk = lookup_peaks(dev.device_kind)
     counter = CompileCounter()
+    mesh, cell_devices = cell_mesh(shape, devices)
     marks.append(("jax_and_devices", time.monotonic()))
     cfg = arch_config(sp["conf"], sp["entry"]["config"])
-    check_layout(sp["conf"], cfg)
+    model = served_model(root, sp, mesh)
+    check_layout(model["shapes"], cfg)
     marks.append(("layout_check", time.monotonic()))
-    from bench import weights
-    params = weights.build(sp["conf"], seed)
+    params = draw_weights(sp, model, seed)
     jax.block_until_ready(params)
     marks.append(("weights", time.monotonic()))
     server = Server(sp, params, trace=trace)
@@ -381,8 +454,9 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
         f"{k}={b - a:.3f}" for (_, a), (k, b) in zip(marks, marks[1:])))
     log(f"memory_bytes_limit {(dev.memory_stats() or {}).get('bytes_limit')}")
     n_compiles = counter.between(t0, t_end)
-    stats_mem = dev.memory_stats() or {}
-    peak = int(stats_mem.get("peak_bytes_in_use", 0))
+    peak = peak_bytes(cell_devices)
+    log(f"memory_peak_bytes_per_device "
+        f"{[peak_bytes([d]) for d in cell_devices]}")
     steps = server.step_records(t0, t0 + seconds)
     server.stop()
     ids = {r["request_id"] for r in records
@@ -412,7 +486,7 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
         offset = tr["marks"]["bench_trace_start"] - prof["mark_perf_s"] * 1e9
         ctx = {"conf": sp["conf"], "cell": sp["cell"], "peaks": pk,
                "steps": steps, "requests": req_recs, "client": e2e,
-               "trace": tr,
+               "trace": tr, "chips": len(cell_devices),
                "clock": lambda s: s * 1e9 + offset, "window_s": seconds}
         for mname, read in spec_mod.metric_readers(root).items():
             v = read(ctx)
@@ -425,8 +499,8 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
                             "idle_gaps": trace_reduce.idle_gaps(tr)}
     server.free()
     t_ref = time.monotonic()
-    widest, ctrl, n_tok = check_outputs(sp, schedule, records, seed, seconds,
-                                        control_bits=control_bits)
+    widest, ctrl, n_tok = check_outputs(sp, model, schedule, records, seed,
+                                        seconds, control_bits=control_bits)
     log(f"reference: {n_tok} served tokens compared in "
         f"{time.monotonic() - t_ref:.1f} s")
     out["widest_gap"], out["control_gap"] = widest, ctrl

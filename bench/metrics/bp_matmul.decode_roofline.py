@@ -1,6 +1,9 @@
 """Kernel ``bitparticle_matmul`` in decode steps: the least time the chip
 needs for the steps' int8 contractions (rows = active slots), over the
-kernel's device time in those steps.  Moves ``itl_p99_ms``."""
+kernel's device time in those steps.  On a mesh, the whole step's work
+at one chip's peaks stands against the kernel's time summed over the
+chips: the share of the chips' combined roofline.  Moves
+``itl_p99_ms``."""
 
 from bench.costs import int8_contractions, roofline_s
 from bench.trace_reduce import kernel_ns, traced_steps

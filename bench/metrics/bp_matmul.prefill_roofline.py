@@ -1,8 +1,11 @@
 """Kernel ``bitparticle_matmul`` in prefill steps: the least time the
 chip needs for the layers' int8 contractions over the prompt tokens (no
 padding) and, when untied, the output head over one row per request,
-over the kernel's device time in those steps.  Moves ``itl_p99_ms``: a
-prefill stalls every decoder, and the longest stall sets the p99 gap."""
+over the kernel's device time in those steps.  On a mesh, the whole
+step's work at one chip's peaks stands against the kernel's time summed
+over the chips: the share of the chips' combined roofline.  Moves
+``itl_p99_ms``: a prefill stalls every decoder, and the longest stall
+sets the p99 gap."""
 
 from bench.costs import int8_contractions, roofline_s
 from bench.trace_reduce import kernel_ns, traced_steps

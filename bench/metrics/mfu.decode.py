@@ -1,6 +1,7 @@
 """Executor / model step: model operations of the rows each decode step
 carried (every linear contraction, output head included; attention left
-out), over the decode steps' wall time at the chip's int8 peak.  Moves
+out), over the decode steps' wall time at the int8 peak of the cell's
+chips (``ctx["chips"]``: the devices of its mesh, 1 without one).  Moves
 ``itl_p99_ms``."""
 
 from bench.costs import matmul_ops_per_token
@@ -13,4 +14,4 @@ def read(ctx):
         return None
     ops = matmul_ops_per_token(ctx["conf"], with_head=True) * sum(
         s["active_slots"] for s in steps)
-    return 100.0 * ops / (wall * ctx["peaks"]["int8_ops"])
+    return 100.0 * ops / (wall * ctx["chips"] * ctx["peaks"]["int8_ops"])

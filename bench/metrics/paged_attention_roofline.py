@@ -3,7 +3,9 @@ read the KV pages each decode step attends over, over the kernel's
 device time in those steps.  The context is taken from the step's
 ``blocks_in_use`` gauge as a lower bound, every block full except one
 token in each active slot's tail block, so the share is never
-overstated.  Moves ``itl_p99_ms``."""
+overstated.  On a mesh, the whole step's work at one chip's peaks stands
+against the kernel's time summed over the chips: the share of the chips'
+combined roofline.  Moves ``itl_p99_ms``."""
 
 from bench.costs import attention_decode_work, roofline_s
 from bench.trace_reduce import kernel_ns, traced_steps

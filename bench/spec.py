@@ -38,21 +38,39 @@ def cell(root: str, name: str) -> dict:
     }
 
 
+def load_module(path: str, prefix: str):
+    """The Python file at ``path``, loaded by file under a name of its own
+    (files are never imported as top-level names)."""
+    stem = os.path.basename(path)[:-3]
+    spec = importlib.util.spec_from_file_location(
+        prefix + stem.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_readers(root: str) -> Dict[str, Callable]:
     """``{metric name: read(ctx)}`` for every ``bench/metrics/<name>.py``."""
     d = os.path.join(root, "bench", "metrics")
-    out = {}
-    for fn in sorted(os.listdir(d)):
-        if not fn.endswith(".py") or fn.startswith("_"):
-            continue
-        name = fn[:-3]
-        spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + name.replace(".", "_").replace("-", "_"),
-            os.path.join(d, fn))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        out[name] = mod.read
-    return out
+    return {fn[:-3]: load_module(os.path.join(d, fn), "bench_metric_").read
+            for fn in sorted(os.listdir(d))
+            if fn.endswith(".py") and not fn.startswith("_")}
+
+
+def reference(root: str, conf: dict):
+    """The configuration's plain reference: the module its
+    ``reference_file`` names (a path from the checkout's root), else
+    ``bench/reference.py``.  It provides ``seq_len`` and ``served_gaps``,
+    and may provide ``layout(conf)`` and ``draw_leaf`` (see
+    ``weights.build``)."""
+    path = conf.get("reference_file")
+    if path is None:
+        from bench import reference as default
+        return default
+    if os.path.isabs(path) or ".." in path.split("/"):
+        raise ValueError(f"reference_file {path!r} must be a path inside "
+                         f"the checkout, from its root")
+    return load_module(os.path.join(root, path), "bench_reference_")
 
 
 def cell_metrics(bm: dict, trace: bool, workload: str = None) -> list:

@@ -27,12 +27,7 @@ ROOT = os.path.dirname(HERE)
 sys.path[:] = [ROOT, os.path.join(ROOT, "src")] + [
     p for p in sys.path if os.path.abspath(p or ".") != HERE]
 
-from bench import harness, spec, stats, traffic, weights  # noqa: E402
-
-
-def peak_bytes():
-    import jax
-    return (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
+from bench import harness, spec, stats, traffic  # noqa: E402
 
 
 def main(argv) -> int:
@@ -44,13 +39,16 @@ def main(argv) -> int:
     args = ap.parse_args(argv)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     sp = spec.cell(ROOT, args.workload)
+    shape = harness.mesh_shape(sp)
     harness.enable_compile_cache(ROOT)
     try:
-        harness.require_accelerator(int(sp["entry"]["chips"]))
+        devices = harness.require_accelerator(int(sp["entry"]["chips"]))
     except harness.NoAccelerator as e:
         harness.log(f"sweep: no accelerator: {e}")
         return harness.EXIT_NO_CHIP
-    params = weights.build(sp["conf"], args.seed)
+    mesh, cell_devices = harness.cell_mesh(shape, devices)
+    params = harness.draw_weights(sp, harness.served_model(ROOT, sp, mesh),
+                                  args.seed)
     server = harness.Server(sp, params, trace=False)
     del params
     server.warm(args.seed)
@@ -81,7 +79,7 @@ def main(argv) -> int:
                 "failed": m["n_failed"],
                 "lateness_p99_ms": stats.lateness_p99_ms(recs),
                 "decode_step_ms": stats.decode_step_ms(steps),
-                "memory_peak_bytes": peak_bytes()}),
+                "memory_peak_bytes": harness.peak_bytes(cell_devices)}),
                 flush=True)
     finally:
         server.stop()

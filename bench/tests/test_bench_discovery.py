@@ -1,6 +1,7 @@
 """Entries are found from files alone; the harness refuses what it cannot
 measure."""
 
+import copy
 import json
 import os
 import subprocess
@@ -10,7 +11,7 @@ import time
 import pytest
 
 import tiny
-from bench import peaks, spec, traffic
+from bench import harness, peaks, spec, traffic, weights
 
 
 def test_new_entries_need_only_new_files(tmp_path):
@@ -48,6 +49,154 @@ def test_new_entries_need_only_new_files(tmp_path):
     assert readers["steps_seen"]({"steps": []}) is None
     names = [m["name"] for m in spec.cell_metrics(bm, True)]
     assert "steps_seen" in names
+
+
+# A copy of the default reference that notes each use of its functions in
+# a file beside it: proof that the harness took the module the
+# configuration names, and took its layout.
+NOTING_REFERENCE = """
+
+_served_gaps = served_gaps
+
+
+def _note(what):
+    with open(__file__ + ".calls", "a") as f:
+        f.write(what + "\\n")
+
+
+def layout(conf):
+    from bench import weights
+    _note("layout")
+    return weights.layout(conf)
+
+
+def served_gaps(*args, **kw):
+    _note("served_gaps")
+    return _served_gaps(*args, **kw)
+"""
+
+
+def test_layout_mesh_and_reference_need_only_new_files(tmp_path, monkeypatch,
+                                                       capsys):
+    """A configuration without QKV bias that names its own reference
+    module, and a cell that names a mesh, run from new files alone."""
+    root = tiny.make_root(tmp_path)
+    d = os.path.join(root, "bench")
+    ref_path = os.path.join(d, "configs", "nobias_reference.py")
+    with open(os.path.join(tiny.BENCH, "reference.py")) as f:
+        src = f.read()
+    with open(ref_path, "w") as f:
+        f.write(src + NOTING_REFERENCE)
+    conf = dict(tiny.CONF, serving=dict(tiny.CONF["serving"], qkv_bias=False),
+                reference_file="bench/configs/nobias_reference.py")
+    cell = copy.deepcopy(tiny.CELL)
+    cell.update(config="nobias")
+    cell["serve"]["mesh_shape"] = [1, 1]
+    with open(os.path.join(d, "configs", "nobias.json"), "w") as f:
+        json.dump(conf, f)
+    with open(os.path.join(d, "workloads", "nobias.small.json"), "w") as f:
+        json.dump(cell, f)
+    bm = spec.benchmark(root)
+    bm["configs"].append({"name": "nobias", "source": "test", "reduced": [],
+                          "file": "bench/configs/nobias.json", "why": "t"})
+    bm["workloads"].append({"name": "nobias.small", "config": "nobias",
+                            "traffic": "small", "chips": 1, "why": "t"})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bm, f)
+
+    from repro.serving.executor import MeshExecutor
+    meshes = []
+    init = MeshExecutor.__init__
+
+    def noting_init(self, *a, **kw):
+        meshes.append(kw["mesh"].devices.shape)
+        init(self, *a, **kw)
+
+    monkeypatch.setattr(MeshExecutor, "__init__", noting_init)
+    tiny.on_cpu(monkeypatch)
+    rc = harness.main(["--workload", "nobias.small", "--seed", "2718281828",
+                       "--seconds", "3", "--trace", "0"], root, 0.0)
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rc == 0 and res["correct"] is True and res["failed"] == 0
+    assert meshes == [(1, 1)]
+    with open(ref_path + ".calls") as f:
+        calls = f.read().split()
+    assert calls[0] == "layout" and "served_gaps" in calls
+    shapes = spec.reference(root, conf).layout(conf)
+    assert "b" not in shapes["layers"]["attn"]["wq"]
+
+
+@pytest.mark.parametrize("shape,chips", [([2, 2], 1), ([1, 2], 4),
+                                         ([4], 4), ([0, 4], 4)])
+def test_mesh_off_the_cell_chips_is_refused_before_building(
+        tmp_path, monkeypatch, shape, chips):
+    cell = copy.deepcopy(tiny.CELL)
+    cell["serve"]["mesh_shape"] = shape
+    root = tiny.make_root(tmp_path, cell=cell, chips=chips)
+
+    def built(*_a, **_kw):
+        raise AssertionError("the harness went on to build")
+
+    for name in ("enable_compile_cache", "require_accelerator",
+                 "served_model", "draw_weights"):
+        monkeypatch.setattr(harness, name, built)
+    with pytest.raises(ValueError, match="mesh_shape"):
+        harness.run_cell(root, "tiny.small", 1, 3.0, False, 0.0)
+
+
+def test_arch_config_forwards_the_serving_fields():
+    from repro.configs.base import ArchConfig
+    conf = spec.load_json(os.path.join(tiny.BENCH, "configs",
+                                       "qwen2-1.5b.json"))
+    # the keys read before keep their meaning
+    assert harness.arch_config(conf, "qwen2-1.5b") == ArchConfig(
+        name="qwen2-1.5b", family="dense", num_layers=28, d_model=1536,
+        num_heads=12, num_kv_heads=2, d_ff=8960, vocab_size=151936,
+        head_dim=128, qkv_bias=True, ffn_type="swiglu", rope_theta=1e6,
+        tie_embeddings=True, norm_eps=1e-6, matmul_mode="bp_exact",
+        matmul_backend="auto")
+    sv = dict(tiny.CONF["serving"], kv_cache_int8=True, num_experts=4,
+              top_k=2, mrope_sections=[2, 3, 3])
+    cfg = harness.arch_config(dict(tiny.CONF, serving=sv), "t")
+    assert (cfg.kv_cache_int8, cfg.num_experts, cfg.top_k,
+            cfg.mrope_sections) == (True, 4, 2, (2, 3, 3))
+    for key in ("name", "matmul_backend", "num_layers"):
+        with pytest.raises(ValueError, match=key):
+            harness.arch_config(dict(tiny.CONF, serving=dict(sv, **{key: 1})),
+                                "t")
+
+
+def test_layout_follows_qkv_bias_and_draws_leaves_by_a_given_rule():
+    import jax
+    import jax.numpy as jnp
+    nobias = dict(tiny.CONF, serving=dict(tiny.CONF["serving"],
+                                          qkv_bias=False))
+    attn = weights.layout(nobias)["layers"]["attn"]
+    assert all("b" not in attn[w] for w in attn)
+    attn = weights.layout(tiny.CONF)["layers"]["attn"]
+    assert ["b" in attn[w] for w in ("wq", "wk", "wv", "wo")] == [
+        True, True, True, False]
+    harness.check_layout(weights.layout(nobias),
+                         harness.arch_config(nobias, "t"))
+    with pytest.raises(ValueError, match="layout"):
+        harness.check_layout(weights.layout(tiny.CONF),
+                             harness.arch_config(nobias, "t"))
+    shapes = dict(weights.layout(tiny.CONF),
+                  gate={"alpha": jax.ShapeDtypeStruct((3,), jnp.float32)})
+    with pytest.raises(ValueError, match="gate/alpha"):
+        weights.build(tiny.CONF, 1, shapes=shapes)
+    drawn = weights.build(
+        tiny.CONF, 1, shapes=shapes,
+        draw=lambda key, path, sds, at: jnp.full(sds.shape, 7.0, sds.dtype))
+    assert drawn["gate"]["alpha"].tolist() == [7.0, 7.0, 7.0]
+
+
+def test_reference_file_stays_inside_the_checkout(tmp_path):
+    for path in ("/abs/ref.py", "bench/../../ref.py"):
+        with pytest.raises(ValueError, match="inside the checkout"):
+            spec.reference(str(tmp_path), {"reference_file": path})
+    from bench import reference
+    assert spec.reference(str(tmp_path), {}) is reference
 
 
 def test_metric_workloads_pick_the_cells():

@@ -49,3 +49,16 @@ def test_traced_run_reports_per_layer_metrics(tmp_path, monkeypatch,
     assert 0 < dev["busy_s"] <= dev["window_s"]
     assert len(res["breakdown"]["device_ops"]) <= 10
     assert len(res["breakdown"]["idle_gaps"]) <= 10
+
+
+def test_peak_memory_is_the_fullest_devices():
+    class Device:
+        def __init__(self, peak):
+            self.stats = None if peak is None else {"peak_bytes_in_use": peak}
+
+        def memory_stats(self):
+            return self.stats
+
+    assert harness.peak_bytes([Device(7)]) == 7
+    assert harness.peak_bytes([Device(3), Device(11), Device(5),
+                               Device(None)]) == 11

@@ -135,3 +135,41 @@ def test_only_tpu_planes_are_chips():
     assert trace_reduce.chip_plane("/device:TPU:0")
     assert not trace_reduce.chip_plane("/device:CUSTOM:Megascale Trace")
     assert not trace_reduce.chip_plane("/host:CPU")
+
+
+def _two_plane_trace(second):
+    """A 100 ns window; device 0 busy over [10, 60], device 1 over
+    ``second``."""
+    ops = [["fusion.1", 10, 60, "", 0]]
+    ops += [["fusion.2", s, e, "", 1] for s, e in second]
+    return {"ops": ops, "host": [], "n_devices": 2,
+            "marks": {"bench_trace_start": 0, "bench_trace_end": 100}}
+
+
+def test_busy_is_each_devices_own_union_averaged():
+    one = {"ops": [["fusion.1", 10, 60, ""]], "host": [], "n_devices": 1,
+           "marks": {"bench_trace_start": 0, "bench_trace_end": 100}}
+    both = _two_plane_trace([(10, 60)])
+    assert trace_reduce.busy_ns(both) == trace_reduce.busy_ns(one) == 50
+    assert trace_reduce.idle_share(both) == trace_reduce.idle_share(one) \
+        == 0.5
+    # device 1 busy elsewhere: each device's busy time, averaged
+    assert trace_reduce.busy_ns(_two_plane_trace([(60, 100)])) == 45
+    assert trace_reduce.busy_ns(_two_plane_trace([])) == 25
+
+
+def test_mfu_divides_by_the_cell_chips():
+    readers = spec.metric_readers(tiny.REPO)
+    steps = [{"kind": "decode", "wall_s": 0.07, "active_slots": 12},
+             {"kind": "decode", "wall_s": 0.08, "active_slots": 9},
+             {"kind": "prefill", "wall_s": 0.2, "prompt_tokens": 3000,
+              "group_size": 2}]
+    ctx = {"conf": QWEN15, "steps": steps,
+           "peaks": {"int8_ops": 393e12}}
+    for name in ("mfu.decode", "mfu.prefill"):
+        one = readers[name](dict(ctx, chips=1))
+        four = readers[name](dict(ctx, chips=4))
+        assert one > 0 and four == pytest.approx(one / 4, rel=1e-12)
+    ops = costs.matmul_ops_per_token(QWEN15, True) * 21
+    assert readers["mfu.decode"](dict(ctx, chips=1)) == pytest.approx(
+        100.0 * ops / (0.15 * 393e12), rel=1e-12)

@@ -44,7 +44,7 @@ PEAKS = {"int8_ops": 1e12, "bf16_flops": 5e11, "hbm_bytes_per_s": 1e11,
          "hbm_bytes": 1e9}
 
 
-def make_root(tmp, *, conf=None, mix=None, cell=None) -> str:
+def make_root(tmp, *, conf=None, mix=None, cell=None, chips=1) -> str:
     root = str(tmp)
     d = os.path.join(root, "bench")
     for sub in ("configs", "traffic", "workloads"):
@@ -57,7 +57,7 @@ def make_root(tmp, *, conf=None, mix=None, cell=None) -> str:
                       "file": "bench/configs/tiny.json", "reduced": [],
                       "why": "test"}]
     bm["workloads"] = [{"name": "tiny.small", "config": "tiny",
-                        "traffic": "small", "chips": 1, "why": "test"}]
+                        "traffic": "small", "chips": chips, "why": "test"}]
     for m in bm["end_to_end"] + bm["per_layer"]:
         m.pop("workloads", None)      # the tiny cell reports every metric
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:

@@ -2,14 +2,16 @@
 
 ``load`` turns the ``.xplane.pb`` of one traced window into a plain dict:
 
-    {"ops":   [[name, start_ns, end_ns, desc], ...],   # device operations
-     "host":  [[name, start_ns, end_ns], ...],          # host spans
-     "marks": {"bench_trace_start": ns, "bench_trace_end": ns}}
+    {"ops":   [[name, start_ns, end_ns, desc, device], ...],  # device ops
+     "host":  [[name, start_ns, end_ns], ...],                 # host spans
+     "marks": {"bench_trace_start": ns, "bench_trace_end": ns},
+     "n_devices": planes}
 
 ``ops`` are the device's operation events (the ``XLA Ops`` line of each
 ``/device:TPU:`` plane; on a CPU run, host events that carry an
-``hlo_op``).  Other ``/device:`` planes, such as ``/device:CUSTOM:
-Megascale Trace``, are not chips.
+``hlo_op``), each with the index of its plane among the chips traced
+(0 on a CPU run, and where an op carries no index).  Other ``/device:``
+planes, such as ``/device:CUSTOM:Megascale Trace``, are not chips.
 ``desc`` joins the event's string stats, where kernel names appear.
 ``host`` keeps the spans named in ``HOST_SPANS`` (the program's own
 ``Tracer`` spans, entered as ``jax.profiler.TraceAnnotation``) and the
@@ -56,12 +58,12 @@ def load(profile_dir: str) -> dict:
     host: List[list] = []
     marks: Dict[str, float] = {}
     device_planes = [p for p in pd.planes if chip_plane(p.name)]
-    for plane in device_planes:
+    for i, plane in enumerate(device_planes):
         for line in plane.lines:
             if line.name != "XLA Ops":
                 continue
             for ev in line.events:
-                ops.append([ev.name, ev.start_ns, ev.end_ns, _desc(ev)])
+                ops.append([ev.name, ev.start_ns, ev.end_ns, _desc(ev), i])
     for plane in pd.planes:
         if not plane.name.startswith("/host:"):
             continue
@@ -75,7 +77,7 @@ def load(profile_dir: str) -> dict:
                     stats = dict(ev.stats)
                     if "hlo_op" in stats:
                         ops.append([ev.name, ev.start_ns, ev.end_ns,
-                                    _desc(ev)])
+                                    _desc(ev), 0])
     ops.sort(key=lambda o: o[1])
     host.sort(key=lambda h: h[1])
     return {"ops": ops, "host": host, "marks": marks,
@@ -109,10 +111,14 @@ def union_ns(intervals) -> float:
 
 
 def busy_ns(trace: dict) -> float:
-    """Time in the traced window in which some operation ran on the
-    device, averaged over the devices traced."""
+    """Time in the traced window in which some operation ran on a device,
+    each device's own, averaged over the devices traced."""
     a, b = window(trace)
-    return union_ns(clip(((o[1], o[2]) for o in trace["ops"]), a, b)) \
+    per_device: Dict[int, list] = {}
+    for o in trace["ops"]:
+        per_device.setdefault(o[4] if len(o) > 4 else 0, []).append(
+            (o[1], o[2]))
+    return sum(union_ns(clip(iv, a, b)) for iv in per_device.values()) \
         / trace.get("n_devices", 1)
 
 
@@ -181,8 +187,8 @@ def _open_span(host: List[list], t: float) -> str:
 
 
 def idle_gaps(trace: dict, k: int = 10) -> List[list]:
-    """The longest stretches with no device operation in the window, each
-    named by the host span open at its middle, in seconds."""
+    """The longest stretches in the window with no operation on any
+    device, each named by the host span open at its middle, in seconds."""
     a, b = window(trace)
     ivs = sorted(clip(((o[1], o[2]) for o in trace["ops"]), a, b))
     gaps, cur = [], a

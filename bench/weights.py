@@ -34,14 +34,16 @@ def padded_vocab(conf: dict) -> int:
 
 def layout(conf: dict):
     """Shape/dtype tree of the served parameters of a dense GQA decoder
-    (Qwen2 layout: QKV bias, SwiGLU, RMSNorm), from the configuration
-    file alone."""
+    (SwiGLU, RMSNorm; a bias on the Q, K and V projections where the
+    configuration's ``serving.qkv_bias`` is true, as in Qwen2), from the
+    configuration file alone."""
     d, f = conf["hidden_size"], conf["intermediate_size"]
     L, H, KH = (conf["num_hidden_layers"], conf["num_attention_heads"],
                 conf["num_key_value_heads"])
     hd = conf.get("head_dim") or d // H
     V = padded_vocab(conf)
     s = jax.ShapeDtypeStruct
+    qkv_bias = bool(conf["serving"]["qkv_bias"])
 
     def dense(k, n, bias=False):
         leaf = {"w": s((L, k, n), jnp.int8), "w_scale": s((L, n), jnp.float32)}
@@ -53,8 +55,10 @@ def layout(conf: dict):
         "embed": {"table": s((V, d), jnp.bfloat16)},
         "layers": {
             "attn_norm": {"scale": s((L, d), jnp.float32)},
-            "attn": {"wq": dense(d, H * hd, True), "wk": dense(d, KH * hd, True),
-                     "wv": dense(d, KH * hd, True), "wo": dense(H * hd, d)},
+            "attn": {"wq": dense(d, H * hd, qkv_bias),
+                     "wk": dense(d, KH * hd, qkv_bias),
+                     "wv": dense(d, KH * hd, qkv_bias),
+                     "wo": dense(H * hd, d)},
             "ffn_norm": {"scale": s((L, d), jnp.float32)},
             "ffn": {"w_gate": dense(d, f), "w_up": dense(d, f),
                     "w_down": dense(f, d)},
@@ -67,7 +71,7 @@ def layout(conf: dict):
     return tree
 
 
-def _leaf(key, path, sds, tree_at):
+def _leaf(key, path, sds, tree_at, draw=None):
     name = path[-1]
     if sds.dtype == jnp.int8:
         bits = jax.random.bits(key, sds.shape, jnp.uint8)
@@ -85,23 +89,31 @@ def _leaf(key, path, sds, tree_at):
         return 1.0 + 0.1 * jax.random.normal(key, sds.shape, sds.dtype)
     if name == "b":
         return 0.02 * jax.random.normal(key, sds.shape, sds.dtype)
+    if draw is not None:
+        return draw(key, path, sds, tree_at)
     raise ValueError(f"no rule to draw parameter {'/'.join(path)} "
                      f"{sds.shape} {sds.dtype}")
 
 
-def build(conf: dict, seed: int):
-    """The served parameter tree, on the default device."""
-    shapes = layout(conf)
+def build(conf: dict, seed: int, shardings=None, *, shapes=None, draw=None):
+    """The served parameter tree of ``shapes`` (default ``layout(conf)``),
+    drawn by one jitted call straight into ``shardings`` (a tree like
+    ``shapes``; None: the default device).  With JAX's partitionable
+    threefry the values do not depend on the shardings.  ``draw(key, path,
+    shape_dtype, tree_at)`` draws a leaf whose name the rules here do not
+    know."""
+    if shapes is None:
+        shapes = layout(conf)
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     paths = [tuple(getattr(p, "key", str(p)) for p in kp) for kp, _ in flat]
     by_path = dict(zip(paths, (s for _, s in flat)))
 
     def make(key):
         keys = jax.random.split(key, len(flat))
-        leaves = [_leaf(k, p, s, by_path.__getitem__)
+        leaves = [_leaf(k, p, s, by_path.__getitem__, draw)
                   for k, p, (_, s) in zip(keys, paths, flat)]
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
-    params = jax.jit(make)(key_from_seed(seed))
+    params = jax.jit(make, out_shardings=shardings)(key_from_seed(seed))
     jax.block_until_ready(params)
     return params
